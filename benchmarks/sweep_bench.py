@@ -54,6 +54,10 @@ import time
 
 import numpy as np
 
+# a host-side scheduling bench: every lane is a process with its own JAX
+# runtime, which only the CPU backend allows (one process per chip)
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from common import host_fingerprint, write_csv  # noqa: E402
 

@@ -13,7 +13,8 @@ Architecture (faithful to the paper):
 
 Params are plain dict pytrees; everything is jit/vmap-friendly. The fused
 Pallas kernel in ``repro.kernels.lstm_cell`` implements the same cell; tests
-assert exact agreement with ``lstm_cell_apply`` below.
+assert exact agreement with ``lstm_cell_apply`` below in the Pallas
+interpreter (compiled on a TPU it agrees within the Tier-1 bound).
 """
 from __future__ import annotations
 
@@ -30,7 +31,18 @@ ENC_OUT = 32
 LSTM_HIDDEN = 32
 LSTM_LAYERS = 2
 
+#: Every matmul of the network — the Pallas cell's included — runs at
+#: full f32 precision.  XLA on a TPU otherwise runs f32 dots in fewer
+#: bf16 passes, and the fused (Tier-1) and reference (Tier-0) programs,
+#: which split and fuse the dots differently, then drift 1.9e-5 apart on
+#: a v5e: past the Tier-1 bound of 1e-5.  On the CPU it changes nothing.
+MATMUL_PRECISION = "highest"
+
 Params = dict  # pytree
+
+
+def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.matmul(a, b, precision=MATMUL_PRECISION)
 
 
 def _dense_init(key, n_in, n_out, scale=None):
@@ -78,14 +90,14 @@ def encoder_apply(params: Params, x: jax.Array) -> jax.Array:
     """4-layer softplus MLP (paper's Encoder network)."""
     h = x
     for layer in params["enc"]:
-        h = jax.nn.softplus(h @ layer["w"] + layer["b"])
+        h = jax.nn.softplus(_mm(h, layer["w"]) + layer["b"])
     return h
 
 
 def lstm_cell_apply(layer: Params, h: jax.Array, c: jax.Array,
                     x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """One LSTM cell step; gates packed [i, f, g, o]."""
-    z = x @ layer["wx"] + h @ layer["wh"] + layer["b"]
+    z = _mm(x, layer["wx"]) + _mm(h, layer["wh"]) + layer["b"]
     i, f, g, o = jnp.split(z, 4, axis=-1)
     c_new = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
     h_new = jax.nn.sigmoid(o) * jnp.tanh(c_new)
@@ -93,18 +105,21 @@ def lstm_cell_apply(layer: Params, h: jax.Array, c: jax.Array,
 
 
 def _cell_apply(layer: Params, h: jax.Array, c: jax.Array, x: jax.Array,
-                use_pallas: bool = False) -> tuple[jax.Array, jax.Array]:
+                use_pallas: bool | str = False
+                ) -> tuple[jax.Array, jax.Array]:
     """Dispatch one cell step to the jnp cell or the fused Pallas kernel
-    (``repro.kernels.lstm_cell``; exact-match tested against
-    :func:`lstm_cell_apply`)."""
+    (``repro.kernels.lstm_cell``).  ``use_pallas=True`` runs the compiled
+    Mosaic kernel (TPU); ``"interpret"`` runs it in the Pallas
+    interpreter (CPU tests)."""
     if not use_pallas:
         return lstm_cell_apply(layer, h, c, x)
     from repro.kernels.lstm_cell import lstm_cell
     batch = h.shape[:-1]
     hid = h.shape[-1]
-    h2, c2 = lstm_cell(x.reshape(-1, x.shape[-1]), h.reshape(-1, hid),
-                       c.reshape(-1, hid), layer["wx"], layer["wh"],
-                       layer["b"])
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        h2, c2 = lstm_cell(x.reshape(-1, x.shape[-1]), h.reshape(-1, hid),
+                           c.reshape(-1, hid), layer["wx"], layer["wh"],
+                           layer["b"], interpret=use_pallas == "interpret")
     return h2.reshape(*batch, hid), c2.reshape(*batch, hid)
 
 
@@ -135,7 +150,7 @@ def step_decoded(params: Params, state: LSTMState, lam: jax.Array,
         cs.append(c_new)
         inp = h_new
     new_state = LSTMState(h=jnp.stack(hs), c=jnp.stack(cs))
-    out = inp @ params["head"]["w"] + params["head"]["b"]
+    out = _mm(inp, params["head"]["w"]) + params["head"]["b"]
     # positivity head: the paper uses ReLU (+1 on alpha); we use softplus —
     # same constraint, but a ReLU alpha-head that initializes negative is
     # DEAD (alpha pinned to 1.0 -> E_S ~ 0 -> START never mitigates).
@@ -198,11 +213,11 @@ def encoder_hoisted(params: Params, mh_ema: jax.Array,
     """
     l0 = params["enc"][0]
     host_dim = mh_ema.shape[-1]
-    lam_h = mh_ema @ l0["w"][:host_dim]             # (T, E) — once per step
-    lam_t = mt @ l0["w"][host_dim:] + l0["b"]       # (nb, E) — once per job
+    lam_h = _mm(mh_ema, l0["w"][:host_dim])         # (T, E) — once per step
+    lam_t = _mm(mt, l0["w"][host_dim:]) + l0["b"]   # (nb, E) — once per job
     h = jax.nn.softplus(lam_h[:, None, :] + lam_t[None, :, :])
     for layer in params["enc"][1:]:
-        h = jax.nn.softplus(h @ layer["w"] + layer["b"])
+        h = jax.nn.softplus(_mm(h, layer["w"]) + layer["b"])
     return h
 
 
@@ -238,7 +253,7 @@ def predict_sequence_opt(params: Params, xs: jax.Array, unroll: int = 1,
     xs = ema_smooth(xs)
     lam = xs
     for layer in params["enc"]:
-        lam = jax.nn.softplus(lam @ layer["w"] + layer["b"])
+        lam = jax.nn.softplus(_mm(lam, layer["w"]) + layer["b"])
     return decode_sequence(params, lam, unroll=unroll,
                            use_pallas=use_pallas)
 

@@ -79,7 +79,7 @@ _N_SCALARS = 2
                    static_argnames=("nb", "task_dim", "use_pallas",
                                     "per_task", "unroll"))
 def _fused_step(params, ring, packed, *, nb: int, task_dim: int,
-                use_pallas: bool = False, per_task: bool = False,
+                use_pallas: bool | str = False, per_task: bool = False,
                 unroll: int = 1):
     """One whole START decision step as a single device program (Tier-1).
 
@@ -232,10 +232,11 @@ class StragglerPredictor:
     # beta_scale so the MSE loss is O(1); alpha is O(1) already
     beta_scale: float = 1.0
     # route the LSTM cell through the fused Pallas kernel
-    # (repro.kernels.lstm_cell); exact-match tested against the jnp cell.
-    # Applies to inference AND training (fit routes train_step through the
-    # same cell; gradients exact-match the reference — tested).
-    use_pallas_cell: bool = False
+    # (repro.kernels.lstm_cell): True runs the compiled Mosaic kernel (a
+    # TPU), "interpret" the Pallas interpreter (CPU tests, exact-match
+    # tested against the jnp cell).  Applies to inference AND training
+    # (fit routes train_step through the same cell).
+    use_pallas_cell: bool | str = False
     # ----- Tier-1 knobs (fused step + serving batch path only) -----
     #: ``lax.scan`` unroll factor for the emission loop.  ``None`` = auto
     #: (full unroll while the horizon is small — deterministic, no
@@ -671,7 +672,7 @@ class StragglerPredictor:
 
     def fit(self, xs: jax.Array, targets: jax.Array, epochs: int = 50,
             lr: float = 1e-5, batch: int = 64,
-            use_pallas_cell: bool | None = None) -> list[float]:
+            use_pallas_cell: bool | str | None = None) -> list[float]:
         """Train on (T, N, input_dim) sequences vs (N, 2) targets.
 
         Minibatches keep one shape: when N > batch the trailing partial
@@ -683,7 +684,6 @@ class StragglerPredictor:
         ``use_pallas_cell`` routes the forward (and, through autodiff,
         the backward) pass of every ``train_step`` through the fused
         Pallas LSTM cell; ``None`` follows the predictor's flag.
-        Gradients exact-match the reference cell (tested).
         """
         n = xs.shape[1]
         use_pallas = (self.use_pallas_cell if use_pallas_cell is None

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 LANES = 128
 NEG_INF = -1e30
@@ -99,7 +98,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, k, v)

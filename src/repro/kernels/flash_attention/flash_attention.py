@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 LANES = 128
 NEG_INF = -1e30
@@ -131,7 +130,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

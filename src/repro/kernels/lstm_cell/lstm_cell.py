@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, h_out, c_out):
@@ -43,7 +43,7 @@ def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, h_out, c_out):
 def lstm_cell_pallas(x: jax.Array, h: jax.Array, c: jax.Array,
                      wx: jax.Array, wh: jax.Array, b: jax.Array, *,
                      block_b: int = 128,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                     interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """x: (B, In); h, c: (B, H); wx: (In, 4H); wh: (H, 4H); b: (4H,)."""
     bsz, n_in = x.shape
     hid = h.shape[1]
@@ -68,7 +68,7 @@ def lstm_cell_pallas(x: jax.Array, h: jax.Array, c: jax.Array,
         out_specs=(pl.BlockSpec((block_b, hid), lambda ib: (ib, 0)),
                    pl.BlockSpec((block_b, hid), lambda ib: (ib, 0))),
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, h, c, wx, wh, b2)

@@ -3,12 +3,17 @@
 ``lstm_cell`` is differentiable: ``pallas_call`` defines no AD rule, so
 the public op carries a ``custom_vjp`` whose forward runs the fused
 kernel and whose backward rematerializes the reference cell and applies
-jax's own VJP to it.  Because the kernel's forward is bitwise-equal to
-the reference (tested), the resulting gradients are *bitwise identical*
-to differentiating the reference cell — training routed through the
-Pallas cell reproduces reference training exactly.
+jax's own VJP to it.  In interpret mode the kernel's forward is
+bitwise-equal to the reference (tested on CPU), so gradients routed
+through the Pallas cell are exactly those of the reference cell.
+
+``interpret`` selects the Pallas interpreter (CPU tests) instead of the
+compiled Mosaic kernel; it defaults to compiled, which is the only mode
+a TPU should run.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +22,7 @@ from repro.kernels.lstm_cell.lstm_cell import lstm_cell_pallas
 from repro.kernels.lstm_cell.ref import lstm_cell_ref
 
 
-def _lstm_cell_fwd_impl(x, h, c, wx, wh, b, block_b=128, interpret=True):
+def _lstm_cell_fwd_impl(x, h, c, wx, wh, b, interpret, block_b=128):
     """Pad batch to the block size, run the fused kernel, unpad."""
     bsz = x.shape[0]
     bb = min(block_b, max(8, 1 << (bsz - 1).bit_length()))
@@ -31,20 +36,19 @@ def _lstm_cell_fwd_impl(x, h, c, wx, wh, b, block_b=128, interpret=True):
     return h2[:bsz], c2[:bsz]
 
 
-@jax.custom_vjp
-def lstm_cell(x, h, c, wx, wh, b):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def lstm_cell(x, h, c, wx, wh, b, interpret=False):
     """Public API; pads batch to the block size and unpads outputs."""
-    return _lstm_cell_fwd_impl(x, h, c, wx, wh, b)
+    return _lstm_cell_fwd_impl(x, h, c, wx, wh, b, interpret)
 
 
-def _lstm_cell_fwd(x, h, c, wx, wh, b):
-    return _lstm_cell_fwd_impl(x, h, c, wx, wh, b), (x, h, c, wx, wh, b)
+def _lstm_cell_fwd(x, h, c, wx, wh, b, interpret):
+    return (_lstm_cell_fwd_impl(x, h, c, wx, wh, b, interpret),
+            (x, h, c, wx, wh, b))
 
 
-def _lstm_cell_bwd(residuals, cotangents):
-    # rematerialize the reference graph and use jax's own VJP of it — the
-    # kernel's forward is bitwise-equal to the reference, so these are
-    # exactly the gradients of the reference cell
+def _lstm_cell_bwd(interpret, residuals, cotangents):
+    # rematerialize the reference graph and use jax's own VJP of it
     _, vjp = jax.vjp(lstm_cell_ref, *residuals)
     return vjp(cotangents)
 

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _scan_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, skip_ref, o_ref,
                  h_scr, *, chunk: int):
@@ -40,17 +38,15 @@ def _scan_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, skip_ref, o_ref,
     skip = skip_ref[...].astype(jnp.float32)    # (1, bd)
 
     def step(t, h):
-        # dynamic time index via pl.dslice: int indices on refs are not
-        # portable across jax versions (0.4.x NDIndexer rejects them)
-        row = (slice(None), pl.dslice(t, 1), slice(None))
-        u_t = pl.load(u_ref, row)[0, 0].astype(jnp.float32)   # (bd,)
-        dt_t = pl.load(dt_ref, row)[0, 0].astype(jnp.float32)  # (bd,)
-        b_t = pl.load(b_ref, row)[0, 0].astype(jnp.float32)    # (N,)
-        c_t = pl.load(c_ref, row)[0, 0].astype(jnp.float32)    # (N,)
+        row = (slice(None), pl.ds(t, 1), slice(None))
+        u_t = u_ref[row][0, 0].astype(jnp.float32)     # (bd,)
+        dt_t = dt_ref[row][0, 0].astype(jnp.float32)   # (bd,)
+        b_t = b_ref[row][0, 0].astype(jnp.float32)     # (N,)
+        c_t = c_ref[row][0, 0].astype(jnp.float32)     # (N,)
         decay = jnp.exp(dt_t[:, None] * a)          # (bd, N)
         h = decay * h + (dt_t * u_t)[:, None] * b_t[None, :]
         y = jnp.sum(h * c_t[None, :], axis=1) + skip[0] * u_t  # (bd,)
-        pl.store(o_ref, row, y[None, None].astype(o_ref.dtype))
+        o_ref[row] = y[None, None].astype(o_ref.dtype)
         return h
 
     h_scr[...] = jax.lax.fori_loop(0, chunk, step, h_scr[...])
@@ -88,7 +84,7 @@ def mamba_scan_pallas(u: jax.Array, delta: jax.Array, a: jax.Array,
                                lambda b_, id_, il: (b_, il, id_)),
         out_shape=jax.ShapeDtypeStruct((bsz, ell, d), u.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, delta, a, b, c, skip2)

@@ -16,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -59,7 +59,7 @@ def moe_router_pallas(logits: jax.Array, k: int, *, block_t: int = 256,
                    pl.BlockSpec((block_t, k), lambda it: (it, 0))),
         out_shape=(jax.ShapeDtypeStruct((t, k), jnp.float32),
                    jax.ShapeDtypeStruct((t, k), jnp.int32)),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(logits)

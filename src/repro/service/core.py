@@ -79,7 +79,7 @@ class ServiceConfig:
     #: ``REPRO_SERVICE_TOKEN``.
     auth_token: str | None = None
     seed: int = 0
-    use_pallas: bool = False
+    use_pallas: bool | str = False
 
 
 class Pending:

@@ -78,7 +78,7 @@ class ReplayBuffer:
 
 
 def shadow_loss(params, eval_xs: np.ndarray, eval_ys: np.ndarray,
-                use_pallas: bool = False) -> float:
+                use_pallas: bool | str = False) -> float:
     """Replay held-back telemetry through a parameter set -> MSE."""
     if eval_xs.shape[1] == 0:
         return float("nan")

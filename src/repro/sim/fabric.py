@@ -28,9 +28,9 @@ other machines**:
     unit still running elsewhere (cells are pure functions of the spec,
     so duplicate execution is value-neutral; first result wins and the
     duplicate is dropped) — the fabric's own straggler mitigation;
-  * opt-in **cache shipping**: with ``ship_cache=True`` and
-    ``REPRO_JAX_CACHE_DIR`` set on the coordinator, joining nodes
-    receive the shared XLA disk cache's files with the grid and
+  * opt-in **cache shipping**: with ``ship_cache=True``, joining nodes
+    receive the coordinator's persistent compilation cache
+    (``repro.jax_runtime.compile_cache_dir``) with the grid and
     warm-start compilation instead of paying cold XLA compiles.
 
 Transport is a **length-prefixed binary frame** protocol over stdlib
@@ -73,12 +73,12 @@ import random
 import socket
 import socketserver
 import struct
-import tempfile
 import threading
 import time
 import uuid
 from collections import deque
 
+from repro import jax_runtime
 from repro.sim import sweep as _sweep
 from repro.sim.sweep import SweepResult, SweepSpec
 
@@ -173,11 +173,12 @@ def recv_frame(f, key: bytes | str | None = None) -> dict | None:
 MAX_CACHE_SHIP_BYTES = 256 * 1024 * 1024
 
 
-def collect_cache_files(path: str | None = None) -> dict[str, bytes]:
-    """Read the shared XLA disk cache into {relpath: bytes} for shipping
-    (empty when ``REPRO_JAX_CACHE_DIR`` is unset/missing)."""
-    path = path or os.environ.get("REPRO_JAX_CACHE_DIR")
-    if not path or not os.path.isdir(path):
+def collect_cache_files() -> dict[str, bytes]:
+    """Read this node's persistent compilation cache into
+    {relpath: bytes} for shipping (empty when the directory is
+    missing)."""
+    path = jax_runtime.compile_cache_dir()
+    if not os.path.isdir(path):
         return {}
     files, total = {}, 0
     for root, _, names in os.walk(path):
@@ -195,18 +196,13 @@ def collect_cache_files(path: str | None = None) -> dict[str, bytes]:
     return files
 
 
-def install_cache_files(files: dict[str, bytes],
-                        path: str | None = None) -> str | None:
-    """Materialize shipped cache files into this node's cache dir (the
-    local ``REPRO_JAX_CACHE_DIR`` if set, else a fresh temp dir which
-    becomes it) and point jax at it.  Existing files are never
-    overwritten — local compiles win races."""
+def install_cache_files(files: dict[str, bytes]) -> str | None:
+    """Materialize shipped cache files into this node's persistent
+    compilation cache directory and point jax at it.  Existing files
+    are never overwritten — local compiles win races."""
     if not files:
         return None
-    path = path or os.environ.get("REPRO_JAX_CACHE_DIR")
-    if not path:
-        path = tempfile.mkdtemp(prefix="repro-fabric-cache-")
-        os.environ["REPRO_JAX_CACHE_DIR"] = path
+    path = jax_runtime.compile_cache_dir()
     for rel, data in files.items():
         full = os.path.join(path, rel)
         if os.path.exists(full):
@@ -216,7 +212,7 @@ def install_cache_files(files: dict[str, bytes],
         with open(tmp, "wb") as f:
             f.write(data)
         os.replace(tmp, full)    # atomic: readers never see partials
-    _sweep.enable_compile_cache()
+    jax_runtime.enable_compile_cache()
     return path
 
 
@@ -289,7 +285,7 @@ class FabricCoordinator:
             scheduling units (elastic membership means the true count
             is unknowable up front; more units than lanes just means
             finer-grained balancing).
-        ship_cache: include the coordinator's ``REPRO_JAX_CACHE_DIR``
+        ship_cache: include the coordinator's compilation cache
             files with the grid so joining nodes warm-start XLA
             compilation (opt-in: shipping megabytes to nodes that
             share a filesystem is waste).
@@ -386,7 +382,7 @@ class FabricCoordinator:
     def _load_grid(self, spec: SweepSpec) -> float:
         """Pretrain + partition ``spec`` and arm it as the current
         epoch's grid; returns the parent-side pretrain seconds."""
-        _sweep.enable_compile_cache()
+        jax_runtime.enable_compile_cache()
         tp = time.perf_counter()
         payloads = _sweep._build_payloads(spec)   # pretrain once, here
         pretrain_s = time.perf_counter() - tp
@@ -591,9 +587,10 @@ class FabricWorker:
 
     ``lanes=1`` runs cells in-process (the agent process is the lane);
     ``lanes>1`` drives a local spawned process pool, so one agent per
-    machine saturates its cores.  The agent heartbeats at
-    ``lease_s / 3`` while computing so long units never look like a
-    dead node.
+    machine saturates its cores — except on an accelerator, where the
+    agent holds the chip and runs every unit itself (one lane).  The
+    agent heartbeats at ``lease_s / 3`` while computing so long units
+    never look like a dead node.
 
     ``run()`` returns when the coordinator goes away (after
     ``reconnect_tries`` failed reconnects) or — with
@@ -786,6 +783,8 @@ class FabricWorker:
         (one, run inline, when ``lanes == 1``), and every finished
         unit's results stream back immediately — the coordinator's
         partial grid grows while the node keeps computing."""
+        if self.lanes > 1 and jax_runtime.on_accelerator():
+            self.lanes = 1      # one process per chip: this one holds it
         self._connect()
         hb = threading.Thread(target=self._heartbeat_loop, daemon=True)
         hb.start()

@@ -29,6 +29,9 @@ Design notes:
     and the pool is *persistent* across ``run()`` calls, so per-worker
     pretrain/warmup caches and XLA jit caches survive between figure
     sweeps (``shutdown_pool()`` tears it down explicitly);
+  * one process per chip: on an accelerator backend the parent holds
+    the device, so ``run()`` runs every cell in-process instead of
+    spawning workers that would need it;
   * scheduling is dynamic and parent-participating: cells are grouped
     into (technique, scenario) cache-affinity units, the parent runs
     units itself while workers spawn/import, and steals back unstarted
@@ -52,6 +55,7 @@ import warnings
 
 import numpy as np
 
+from repro import jax_runtime
 from repro.policy import Policy, PretrainContext
 from repro.sim import scenarios as S
 from repro.sim.config import SimConfig
@@ -84,7 +88,8 @@ class SweepSpec:
     arrival_rate: float = 0.6
     overrides: tuple = ()          # ((SimConfig field, value), ...) per cell
     metrics: tuple = QOS_KEYS
-    max_workers: int | None = None  # None -> cpu_count; <= 1 -> serial
+    # None -> cpu_count; <= 1 -> serial; always serial on an accelerator
+    max_workers: int | None = None
     out_dir: str | None = None      # write CSV artifacts here when set
     csv_prefix: str = "sweep"
     pretrain_epochs: int = 8        # START encoder-LSTM pretraining epochs
@@ -358,34 +363,13 @@ def _run_unit_star(args) -> list[CellResult]:
     return _run_unit(*args)
 
 
-def enable_compile_cache() -> str | None:
-    """Point jax at a shared on-disk compilation cache (idempotent).
-
-    Every sweep worker compiles the same XLA programs (the fused START
-    step per batch bucket, train steps, ...); a shared persistent cache
-    means the first process to compile a program writes it and every
-    other worker — including freshly spawned cold pools — loads the
-    identical executable from disk instead of recompiling.  Executables
-    are bit-identical by construction, so results are unaffected.
-
-    Opt-in: set ``REPRO_JAX_CACHE_DIR=<path>`` (disabled by default —
-    on hosts with slow/contended disks the cache's per-hit bookkeeping
-    can cost more than the recompiles it saves).
-    """
-    path = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if not path or path in ("off", "0"):
-        return None
-    import jax
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return path
-
-
 def _worker_init(worker_seq=None, pin_cores: bool = False) -> None:
     """Pool-worker initializer: optionally pin the worker to its own
-    core, enable the shared compilation cache before anything traces,
-    then pay the import cost (jax + simulator stack) up front — spawn
-    overlaps the parent's pretraining and first locally-run units.
+    core, enable the shared compilation cache before anything traces
+    (every worker compiles the same programs; the first to compile one
+    writes it and the others load it), then pay the import cost (jax +
+    simulator stack) up front — spawn overlaps the parent's pretraining
+    and first locally-run units.
 
     Pinning applies only when workers >= physical cores: each worker's
     XLA runtime sizes its intra-op pool from the scheduling affinity, so
@@ -401,7 +385,7 @@ def _worker_init(worker_seq=None, pin_cores: bool = False) -> None:
             worker_seq.value += 1
         cpus = sorted(os.sched_getaffinity(0))
         os.sched_setaffinity(0, {cpus[idx % len(cpus)]})
-    enable_compile_cache()
+    jax_runtime.enable_compile_cache()
     import repro.sim.engine  # noqa: F401
 
 
@@ -667,8 +651,10 @@ def _schedule_units(spec: SweepSpec, n_workers: int) -> list[tuple]:
 
 def run(spec: SweepSpec, *, fabric=None) -> SweepResult:
     """Execute the sweep grid; parallel over the persistent spawned process
-    pool unless ``spec.max_workers <= 1``. Cell order in the result is
-    deterministic (scenario-major, as produced by ``spec.cells()``).
+    pool unless ``spec.max_workers <= 1`` or JAX's default backend is an
+    accelerator (then this one process runs every cell). Cell order in
+    the result is deterministic (scenario-major, as produced by
+    ``spec.cells()``).
 
     ``fabric`` accepts a started :class:`repro.sim.fabric.
     FabricCoordinator`: the grid is then served to its remote node
@@ -691,11 +677,13 @@ def run(spec: SweepSpec, *, fabric=None) -> SweepResult:
     """
     if fabric is not None:
         return fabric.run_grid(spec)
-    enable_compile_cache()
+    jax_runtime.enable_compile_cache()
     cells = spec.cells()
     n_workers = spec.max_workers
     if n_workers is None:
         n_workers = min(len(cells), os.cpu_count() or 1)
+    if n_workers > 1 and jax_runtime.on_accelerator():
+        n_workers = 1       # one process per chip: this one holds it
     t0 = time.perf_counter()
     pretrain_s = 0.0
     if n_workers <= 1 or len(cells) <= 1:

@@ -16,11 +16,13 @@ import io
 import json
 import multiprocessing
 import os
+import pathlib
 import threading
 import time
 
 import pytest
 
+from repro import jax_runtime
 from repro.sim import fabric, sweep
 from repro.sim.fabric import (FabricCoordinator, FabricWorker,
                               ProtocolError, recv_frame, send_frame,
@@ -193,22 +195,22 @@ def test_drain_only_after_grid_completes(coord):
 
 def test_cache_shipping_roundtrip(tmp_path, monkeypatch):
     # keep the test from pointing the process-wide jax cache at tmp_path
-    monkeypatch.setattr(sweep, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(jax_runtime, "enable_compile_cache", lambda: None)
     src = tmp_path / "src-cache"
     src.mkdir()
     (src / "prog_a.bin").write_bytes(b"exec-a")
     sub = src / "sub"
     sub.mkdir()
     (sub / "prog_b.bin").write_bytes(b"exec-b")
-    monkeypatch.setenv("REPRO_JAX_CACHE_DIR", str(src))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(src))
     files = fabric.collect_cache_files()
     assert files == {"prog_a.bin": b"exec-a",
                      os.path.join("sub", "prog_b.bin"): b"exec-b"}
-    # worker side: no local cache dir -> temp dir materialized
+    # worker side: shipped files land in this node's cache dir
     dst = tmp_path / "dst-cache"
     dst.mkdir()
     (dst / "prog_a.bin").write_bytes(b"local-wins")
-    monkeypatch.setenv("REPRO_JAX_CACHE_DIR", str(dst))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(dst))
     path = fabric.install_cache_files(files)
     assert path == str(dst)
     # existing files never overwritten; missing ones shipped in
@@ -216,10 +218,31 @@ def test_cache_shipping_roundtrip(tmp_path, monkeypatch):
     assert (dst / "sub" / "prog_b.bin").read_bytes() == b"exec-b"
 
 
-def test_collect_cache_files_empty_when_unset(monkeypatch):
-    monkeypatch.delenv("REPRO_JAX_CACHE_DIR", raising=False)
+def test_collect_cache_files_empty_when_unset(tmp_path, monkeypatch):
+    # env unset and the default directory not yet created: nothing to ship
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax_runtime, "DEFAULT_CACHE_DIR",
+                        tmp_path / "never-created")
     assert fabric.collect_cache_files() == {}
     assert fabric.install_cache_files({}) is None
+
+
+def test_compile_cache_env_var_wins_over_default(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    assert jax_runtime.compile_cache_dir() == str(tmp_path / "c")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
+    assert jax_runtime.compile_cache_dir() == str(
+        jax_runtime.DEFAULT_CACHE_DIR)
+
+
+def test_compile_cache_default_is_fixed_and_inside_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    path = pathlib.Path(jax_runtime.compile_cache_dir())
+    assert path == checkout / ".jax_cache"
+    assert jax_runtime.compile_cache_dir() == str(path)   # no per-call name
+    ignored = (checkout / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
 
 
 # ------------------------------ CLI helpers --------------------------------
@@ -255,6 +278,32 @@ def test_fabric_run_in_thread_bitwise_equals_serial():
             w.stop()
     assert [(c.scenario, c.technique, c.seed) for c in res.cells] == \
         spec.cells()
+    for a, b in zip(serial.cells, res.cells):
+        assert _det(a.summary) == _det(b.summary)
+    th.join(timeout=10)
+
+
+def test_worker_runs_inline_on_accelerator(monkeypatch):
+    """One process per chip: an agent asked for 4 lanes on an
+    accelerator backend runs every unit itself instead of spawning a
+    local pool whose children would need the chip."""
+    monkeypatch.setattr(jax_runtime, "on_accelerator", lambda: True)
+
+    def no_pool(self):
+        raise AssertionError("spawned a local pool on an accelerator")
+    monkeypatch.setattr(FabricWorker, "_local_pool", no_pool)
+    spec = _spec(seeds=(0,))
+    serial = run(spec)
+    with FabricCoordinator(lease_s=30.0) as coord:
+        w = FabricWorker(coord.host, coord.port, node="chip", lanes=4,
+                         exit_on_drain=False)
+        th = threading.Thread(target=w.run, daemon=True)
+        th.start()
+        try:
+            res = run(spec, fabric=coord)
+        finally:
+            w.stop()
+    assert w.lanes == 1
     for a, b in zip(serial.cells, res.cells):
         assert _det(a.summary) == _det(b.summary)
     th.join(timeout=10)

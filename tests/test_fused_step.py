@@ -17,6 +17,7 @@ Pins the fused path's contracts under the tiered determinism model:
     per-worker duplicate pretraining.
 """
 import dataclasses
+import functools
 import pickle
 
 import jax
@@ -185,6 +186,7 @@ def test_lstm_cell_gradients_exact_match_reference():
     slightly different transpose sequences and lands within an ulp; the
     jitted whole-graph comparison is the contract.)"""
     from repro.kernels.lstm_cell import lstm_cell, lstm_cell_ref
+    lstm_cell = functools.partial(lstm_cell, interpret=True)
     rng = np.random.default_rng(3)
     layer = net._lstm_init(jax.random.PRNGKey(3), 32, 32)
     x, h, c = (np.asarray(rng.normal(size=(8, 32)), np.float32)
@@ -202,7 +204,7 @@ def test_lstm_cell_gradients_exact_match_reference():
 
 
 def test_fit_through_pallas_cell_reproduces_reference_training():
-    """StragglerPredictor.fit(use_pallas_cell=True) routes every train
+    """StragglerPredictor.fit(use_pallas_cell=...) routes every train
     step through the fused cell.  The isolated cell gradient is bitwise
     exact (test above); inside the full train-step graph XLA may fuse
     the surrounding network differently per path, so whole-training
@@ -214,7 +216,8 @@ def test_fit_through_pallas_cell_reproduces_reference_training():
     xs = rng.normal(size=(5, 8, dim)).astype(np.float32)
     ys = np.abs(rng.normal(size=(8, 2))).astype(np.float32) + 1.0
     l_ref = ref.fit(xs, ys, epochs=2, lr=1e-3)
-    l_pal = pal.fit(xs, ys, epochs=2, lr=1e-3, use_pallas_cell=True)
+    l_pal = pal.fit(xs, ys, epochs=2, lr=1e-3,
+                    use_pallas_cell="interpret")
     np.testing.assert_allclose(l_ref, l_pal, rtol=1e-6)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(

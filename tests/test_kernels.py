@@ -156,7 +156,7 @@ def test_lstm_cell_sweep(bsz, nin, hid, dtype):
     wx = jax.random.normal(ks[3], (nin, 4 * hid), dtype) * 0.2
     wh = jax.random.normal(ks[4], (hid, 4 * hid), dtype) * 0.2
     b = jax.random.normal(ks[5], (4 * hid,), dtype) * 0.1
-    h2, c2 = lstm_cell(x, h, c, wx, wh, b)
+    h2, c2 = lstm_cell(x, h, c, wx, wh, b, interpret=True)
     hr, cr = lstm_cell_ref(x, h, c, wx, wh, b)
     np.testing.assert_allclose(np.asarray(h2, np.float32),
                                np.asarray(hr, np.float32), **tol(dtype))
@@ -171,7 +171,8 @@ def test_lstm_kernel_matches_core_network_cell():
     h = jnp.zeros((16, 32))
     c = jnp.zeros((16, 32))
     h1, c1 = net.lstm_cell_apply(layer, h, c, x)
-    h2, c2 = lstm_cell(x, h, c, layer["wx"], layer["wh"], layer["b"])
+    h2, c2 = lstm_cell(x, h, c, layer["wx"], layer["wh"], layer["b"],
+                       interpret=True)
     np.testing.assert_allclose(h1, h2, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(c1, c2, rtol=1e-5, atol=1e-6)
 

@@ -267,14 +267,17 @@ def test_start_cell_run_stays_within_bucket_compiles():
 
 def test_predict_sequence_pallas_route_is_exact():
     """The fused Pallas LSTM cell behind ``use_pallas`` must reproduce the
-    jnp cell bit-for-bit through the full network."""
+    jnp cell bit-for-bit through the full network (Pallas interpreter on
+    the CPU; the compiled kernel is checked on the chip by
+    ``chip_smoke.py``)."""
     params = net.init_params(jax.random.PRNGKey(0), input_dim=24)
     xs = jax.random.normal(jax.random.PRNGKey(1), (5, 6, 24), jnp.float32)
     ref = net.predict_sequence(params, xs)
-    pal = net.predict_sequence(params, xs, use_pallas=True)
+    pal = net.predict_sequence(params, xs, use_pallas="interpret")
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(pal))
     # and via the predictor flag
-    pred = StragglerPredictor(n_hosts=2, max_tasks=4, use_pallas_cell=True)
+    pred = StragglerPredictor(n_hosts=2, max_tasks=4,
+                              use_pallas_cell="interpret")
     mh = np.zeros((5, 2, features.HOST_FEATURES), np.float32)
     mt = np.zeros((3, 4, features.TASK_FEATURES), np.float32)
     out = pred.predict_features(mh, mt, np.full(3, 4.0, np.float32))

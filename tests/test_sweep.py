@@ -8,6 +8,7 @@ import os
 import numpy as np
 import pytest
 
+from repro import jax_runtime
 from repro.core import pareto
 from repro.sim import SimConfig, Simulation, scenarios, small, sweep
 from repro.sim import engine as E
@@ -119,6 +120,23 @@ def test_sweep_parallel_bitwise_equals_serial():
         assert (a.scenario, a.technique, a.seed) == (b.scenario,
                                                      b.technique, b.seed)
         assert _det(a.summary) == _det(b.summary), (a.scenario, a.technique)
+
+
+def test_sweep_runs_in_process_on_accelerator(monkeypatch):
+    """One process per chip: with an accelerator as JAX's default
+    backend, a parallel spec spawns no pool — the process holding the
+    chip runs every cell, with the serial results."""
+    spec = _tiny_spec()
+    serial = run(spec)
+    monkeypatch.setattr(jax_runtime, "on_accelerator", lambda: True)
+
+    def no_pool(n_workers):
+        raise AssertionError("spawned a worker pool on an accelerator")
+    monkeypatch.setattr(sweep, "_pool", no_pool)
+    chip = run(dataclasses.replace(spec, max_workers=4))
+    assert chip.n_workers == 1
+    for a, b in zip(serial.cells, chip.cells):
+        assert _det(a.summary) == _det(b.summary)
 
 
 def test_sweep_parallel_equals_serial_with_pretrained_technique():
