@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core import encoder_lstm as net
 from repro.core import features, pareto
+from repro.trace import span
 
 
 class Prediction(NamedTuple):
@@ -283,6 +284,11 @@ class StragglerPredictor:
         self._stage_bufs: dict[int, np.ndarray] = {}  # per-bucket staging
         self._scalar_cache = None  # device (k, beta_scale) for serving
         self.h2d_stages = 0        # host->device staging uploads performed
+        self.fused_calls = 0       # fused-step dispatches
+        self.catchup_rolls = 0     # idle-interval rows rolled in alone
+        self.ring_rebuilds = 0     # rings rebuilt from the host history
+        self.rows_real = 0         # real job rows over the fused calls
+        self.rows_dispatched = 0   # batch rows over the fused calls
 
     def __getstate__(self):
         # the device ring is a pure cache of `_row_hist`; drop it so
@@ -426,6 +432,7 @@ class StragglerPredictor:
             while len(hist) < t:
                 hist.insert(0, hist[0])
             self._ring = self._stage(np.stack(hist[-t:]))
+            self.ring_rebuilds += 1
         else:
             # roll in every lagging row but the newest (idle-interval
             # catch-up; the common warm interval has exactly one).  The
@@ -435,6 +442,7 @@ class StragglerPredictor:
             ring, self._ring = self._ring, None
             for row in rows[-lag:-1]:
                 ring = _ring_roll(ring, self._stage(row))
+                self.catchup_rolls += 1
             self._ring = ring
         self._ring_rows = self._host_rows - 1
         return rows[-1]
@@ -456,8 +464,42 @@ class StragglerPredictor:
         """
         n = m_t.shape[0]
         nb = self.batch_size(n)
-        self.buckets_used.add(nb)
-        row = self._sync_ring()
+        with span("predictor.interval", n=n, nb=nb):
+            self.buckets_used.add(nb)
+            with span("predictor.sync_ring", n=n, nb=nb):
+                row = self._sync_ring()
+            with span("predictor.pack", n=n, nb=nb):
+                buf = self._pack(row, m_t, q, n, nb)
+            with span("predictor.dispatch", n=n, nb=nb):
+                # donated: invalid on failure
+                ring, self._ring = self._ring, None
+                try:
+                    ring2, out = _fused_step(
+                        self.params, ring, self._stage(buf), nb=nb,
+                        task_dim=self.task_dim,
+                        use_pallas=self.use_pallas_cell,
+                        per_task=per_task, unroll=self._unroll(nb))
+                except Exception:
+                    self._ring_rows = 0          # next call rebuilds the ring
+                    raise
+                self._ring = ring2
+                self._ring_rows += 1
+                self.fused_calls += 1
+                self.rows_real += n
+                self.rows_dispatched += nb
+            with span("predictor.readback", n=n, nb=nb):
+                out = np.asarray(out)
+        if per_task:
+            # packed [E_S | scores] computed inside the fused program —
+            # one readback, no second dispatch
+            return out[:n, 0], out[:n, 1:]
+        return out[:n]
+
+    def _pack(self, row: np.ndarray, m_t: np.ndarray, q: np.ndarray,
+              n: int, nb: int) -> np.ndarray:
+        """Fill the bucket's staging buffer (the packed layout of
+        ``_fused_step``) with the scalars, the newest host row, q and the
+        M_T batch, padded to ``nb`` rows."""
         host_dim = self.host_dim
         task_dim = self.task_dim
         size = _N_SCALARS + host_dim + nb * (1 + task_dim)
@@ -473,23 +515,7 @@ class StragglerPredictor:
         mt = buf[_N_SCALARS + host_dim + nb:]
         mt[:n * task_dim] = np.asarray(m_t, np.float32).reshape(-1)
         mt[n * task_dim:] = 0.0
-        ring, self._ring = self._ring, None   # donated: invalid on failure
-        try:
-            ring2, out = _fused_step(
-                self.params, ring, self._stage(buf), nb=nb,
-                task_dim=task_dim, use_pallas=self.use_pallas_cell,
-                per_task=per_task, unroll=self._unroll(nb))
-        except Exception:
-            self._ring_rows = 0               # next call rebuilds the ring
-            raise
-        self._ring = ring2
-        self._ring_rows += 1
-        if per_task:
-            # packed [E_S | scores] computed inside the fused program —
-            # one readback, no second dispatch
-            out = np.asarray(out)
-            return out[:n, 0], out[:n, 1:]
-        return np.asarray(out)[:n]
+        return buf
 
     # ------------------------ multi-tenant serving -------------------------
 

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core import mitigation
 from repro.core.predictor import StragglerPredictor
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -214,8 +215,9 @@ class STARTController:
             return self._decide_per_task(job_ids, m_t, q, deadline,
                                          incomplete_fn, host_load)
         e_s = self.predict_es_batch(job_ids, m_t, q)
-        return self.apply_milestone(job_ids, e_s, open_counts, deadline,
-                                    incomplete_fn, host_load)
+        with span("start.trigger"):
+            return self.apply_milestone(job_ids, e_s, open_counts,
+                                        deadline, incomplete_fn, host_load)
 
     def apply_milestone(self, job_ids: np.ndarray, e_s: np.ndarray,
                         open_counts: np.ndarray, deadline: np.ndarray,
@@ -266,8 +268,9 @@ class STARTController:
         at a contended host (its streak keeps building meanwhile, so the
         fire is deferred, not forgotten)."""
         e_s, scores = self.predict_scores_batch(job_ids, m_t, q)
-        return self.apply_per_task(job_ids, e_s, scores, deadline,
-                                   incomplete_fn, host_load)
+        with span("start.trigger"):
+            return self.apply_per_task(job_ids, e_s, scores, deadline,
+                                       incomplete_fn, host_load)
 
     def apply_per_task(self, job_ids: np.ndarray, e_s: np.ndarray,
                        scores: np.ndarray, deadline: np.ndarray,

@@ -39,6 +39,7 @@ from repro.sim.config import SimConfig
 from repro.sim.faults import FaultInjector, FaultKind
 from repro.sim.scheduler import Scheduler, UtilizationAwareScheduler
 from repro.sim.workload import WorkloadGenerator
+from repro.trace import span
 
 __all__ = ["PENDING", "RUNNING", "DONE", "CANCELLED", "TaskTable",
            "JobTable", "SimAction", "Technique", "NoMitigation",
@@ -291,126 +292,144 @@ class Simulation:
     # ---------------------------- main stepping ----------------------------
 
     def step(self) -> None:
-        cfg, tt = self.cfg, self.tasks
-        self.cluster.begin_interval()
-        self._interval_straggler_done = []
+        with span("sim.step", t=self.t):
+            self._step(self.t)
 
-        # 1. arrivals (batched task insertion)
-        batch = self.workload.sample_interval(self.t)
-        new_idx = tt.add_batch(
-            len(batch.job_ids), job_id=batch.job_ids, state=PENDING,
-            work=batch.work, submit_s=self.now_s,
-            deadline_s=batch.deadline_rel, is_deadline=batch.is_deadline,
-            sla_weight=batch.sla_weight)
-        if len(new_idx):
-            tt.req[new_idx] = batch.req
-            # whole jobs arrive as contiguous task blocks with dense,
-            # sequential ids — register them in the CSR job table
-            firsts = np.nonzero(np.r_[True,
-                                      batch.job_ids[1:]
-                                      != batch.job_ids[:-1]])[0]
-            counts = np.diff(np.r_[firsts, len(batch.job_ids)])
-            if (batch.job_ids[firsts]
-                    != np.arange(self.jobs.n,
-                                 self.jobs.n + len(firsts))).any():
-                raise AssertionError(
-                    "workload batches must emit dense, sequential job ids "
-                    "with each job's tasks contiguous (CSR job index)")
-            self.jobs.add_batch(new_idx[firsts], counts,
-                                batch.is_deadline[firsts])
+    def _step(self, t: int) -> None:
+        cfg, tt = self.cfg, self.tasks
+
+        # 1. host downtimes tick down; arrivals (batched task insertion)
+        with span("sim.arrivals", t=t):
+            self.cluster.begin_interval()
+            self._interval_straggler_done = []
+            batch = self.workload.sample_interval(t)
+            new_idx = tt.add_batch(
+                len(batch.job_ids), job_id=batch.job_ids, state=PENDING,
+                work=batch.work, submit_s=self.now_s,
+                deadline_s=batch.deadline_rel,
+                is_deadline=batch.is_deadline, sla_weight=batch.sla_weight)
+            if len(new_idx):
+                tt.req[new_idx] = batch.req
+                # whole jobs arrive as contiguous task blocks with dense,
+                # sequential ids — register them in the CSR job table
+                firsts = np.nonzero(np.r_[True,
+                                          batch.job_ids[1:]
+                                          != batch.job_ids[:-1]])[0]
+                counts = np.diff(np.r_[firsts, len(batch.job_ids)])
+                if (batch.job_ids[firsts]
+                        != np.arange(self.jobs.n,
+                                     self.jobs.n + len(firsts))).any():
+                    raise AssertionError(
+                        "workload batches must emit dense, sequential job "
+                        "ids with each job's tasks contiguous (CSR job "
+                        "index)")
+                self.jobs.add_batch(new_idx[firsts], counts,
+                                    batch.is_deadline[firsts])
 
         # 2. policy submit-time decision point (clone / delay) — skipped
         # for policies that declare submit_hook=False (the view and an
         # ignoring decide() are both pure, so this is behavior-preserving)
-        t0 = _time.perf_counter()
-        if getattr(self.technique, "submit_hook", True):
-            for act in self.technique.decide(self.snapshot(EVENT_SUBMIT,
-                                                           new_idx)):
-                self._apply(act)
-        submit_overhead = _time.perf_counter() - t0
+        with span("sim.submit", t=t):
+            t0 = _time.perf_counter()
+            if getattr(self.technique, "submit_hook", True):
+                for act in self.technique.decide(
+                        self.snapshot(EVENT_SUBMIT, new_idx)):
+                    self._apply(act)
+            submit_overhead = _time.perf_counter() - t0
 
         # 3. schedule pending tasks whose delay has expired — one
         # place_batch call for the whole interval (bitwise-equal to the
         # old per-task loop), then bounce VM-creation-fault placements
-        events = self.faults.interval_events()
-        vm_fault_hosts = [e.host for e in events
-                          if e.kind == FaultKind.VM_CREATION]
-        ready = np.nonzero((tt.view("state") == PENDING)
-                           & (tt.view("delayed_until") <= self.t))[0]
-        if ready.size:
-            hosts = self.scheduler.place_batch(
-                self.cluster, tt.req[ready], self.rng,
-                exclude=tt.prev_host[ready])
-            tt.host[ready] = hosts
-            tt.state[ready] = RUNNING
-            fresh = ready[tt.start_s[ready] == 0.0]
-            tt.start_s[fresh] = self.now_s
-            if vm_fault_hosts:
-                bounced = ready[np.isin(hosts, vm_fault_hosts)]
-                if bounced.size:                # VM creation fault: bounce
-                    tt.state[bounced] = PENDING  # to next interval; avoid
-                    tt.restarts[bounced] += 1    # the host on re-place; a
-                    tt.prev_host[bounced] = tt.host[bounced]  # pending task
-                    tt.host[bounced] = -1        # holds no host
+        with span("sim.place", t=t):
+            events = self.faults.interval_events()
+            vm_fault_hosts = [e.host for e in events
+                              if e.kind == FaultKind.VM_CREATION]
+            ready = np.nonzero((tt.view("state") == PENDING)
+                               & (tt.view("delayed_until") <= t))[0]
+            if ready.size:
+                hosts = self.scheduler.place_batch(
+                    self.cluster, tt.req[ready], self.rng,
+                    exclude=tt.prev_host[ready])
+                tt.host[ready] = hosts
+                tt.state[ready] = RUNNING
+                fresh = ready[tt.start_s[ready] == 0.0]
+                tt.start_s[fresh] = self.now_s
+                if vm_fault_hosts:
+                    # VM creation fault: bounce to the next interval and
+                    # avoid the host on re-place; a pending task holds no
+                    # host
+                    bounced = ready[np.isin(hosts, vm_fault_hosts)]
+                    if bounced.size:
+                        tt.state[bounced] = PENDING
+                        tt.restarts[bounced] += 1
+                        tt.prev_host[bounced] = tt.host[bounced]
+                        tt.host[bounced] = -1
 
         # 4. fault events: host downtime restarts residents, cloudlet
         # faults restart sampled active tasks (both batched)
-        failed = [ev for ev in events if ev.kind == FaultKind.HOST]
-        for ev in failed:
-            self.cluster.fail_host(ev.host, ev.downtime)
-        if failed:
-            self._restart_batch(np.nonzero(
-                (tt.view("state") == RUNNING)
-                & np.isin(tt.view("host"),
-                          [ev.host for ev in failed]))[0])
-        active = tt.active_mask()
-        cl_faults = self.faults.cloudlet_faults(int(active.sum()))
-        self._restart_batch(np.nonzero(active)[0][cl_faults])
+        with span("sim.faults", t=t):
+            failed = [ev for ev in events if ev.kind == FaultKind.HOST]
+            for ev in failed:
+                self.cluster.fail_host(ev.host, ev.downtime)
+            if failed:
+                self._restart_batch(np.nonzero(
+                    (tt.view("state") == RUNNING)
+                    & np.isin(tt.view("host"),
+                              [ev.host for ev in failed]))[0])
+            active = tt.active_mask()
+            cl_faults = self.faults.cloudlet_faults(int(active.sum()))
+            self._restart_batch(np.nonzero(active)[0][cl_faults])
 
         # 5. policy interval decision point (speculate / rerun): one view
         # feeds telemetry ingestion and the decision — same state, built
         # zero-copy once
-        t0 = _time.perf_counter()
-        view = self.snapshot(EVENT_INTERVAL)
-        self.technique.observe(view)
-        for act in self.technique.decide(view):
-            self._apply(act)
-        predicted = self.technique.predicted_straggler_count()
-        interval_overhead = _time.perf_counter() - t0 + submit_overhead
+        with span("sim.policy", t=t):
+            t0 = _time.perf_counter()
+            view = self.snapshot(EVENT_INTERVAL)
+            self.technique.observe(view)
+            for act in self.technique.decide(view):
+                self._apply(act)
+            predicted = self.technique.predicted_straggler_count()
+            interval_overhead = (_time.perf_counter() - t0
+                                 + submit_overhead)
 
         # 6. progress
-        active = tt.active_mask()
-        self.cluster.recompute_utilization(tt.view("req")[:, :],
-                                           tt.view("host"), active)
-        rate = self.cluster.effective_speed() * self.host_ips  # MI/s, per host
-        run = np.nonzero(active)[0]
-        inc = rate[tt.host[run]] * cfg.interval_seconds
-        prog0 = tt.progress[run]
-        tt.progress[run] = prog0 + inc
-        finished = tt.progress[run] >= tt.work[run]
-        fin_idx = run[finished]
-        if fin_idx.size:
-            frac = np.clip((tt.work[fin_idx] - prog0[finished])
-                           / np.maximum(inc[finished], 1e-9), 0, 1)
-            fins = self.now_s + frac * cfg.interval_seconds
-            # first-result-wins is decided by interpolated finish time:
-            # complete earliest-first and skip tasks a sibling already
-            # cancelled (or completed) earlier within this interval
-            order = np.argsort(fins, kind="stable")
-            for i, fs in zip(fin_idx[order], fins[order]):
-                if tt.state[i] == RUNNING:
-                    self._complete(int(i), float(fs))
+        with span("sim.progress", t=t):
+            active = tt.active_mask()
+            self.cluster.recompute_utilization(tt.view("req")[:, :],
+                                               tt.view("host"), active)
+            # MI/s, per host
+            rate = self.cluster.effective_speed() * self.host_ips
+            run = np.nonzero(active)[0]
+            inc = rate[tt.host[run]] * cfg.interval_seconds
+            prog0 = tt.progress[run]
+            tt.progress[run] = prog0 + inc
+            finished = tt.progress[run] >= tt.work[run]
+            fin_idx = run[finished]
+            if fin_idx.size:
+                frac = np.clip((tt.work[fin_idx] - prog0[finished])
+                               / np.maximum(inc[finished], 1e-9), 0, 1)
+                fins = self.now_s + frac * cfg.interval_seconds
+                # first-result-wins is decided by interpolated finish
+                # time: complete earliest-first and skip tasks a sibling
+                # already cancelled (or completed) earlier within this
+                # interval
+                order = np.argsort(fins, kind="stable")
+                for i, fs in zip(fin_idx[order], fins[order]):
+                    if tt.state[i] == RUNNING:
+                        self._complete(int(i), float(fs))
 
-        self.util_history.append(self.cluster.util.copy())
+            self.util_history.append(self.cluster.util.copy())
 
         # 7. metrics + ground-truth straggler accounting
-        cont = M.contention_metric(self.cluster, tt.view("req"),
-                                   tt.view("host"), tt.active_mask())
-        self.log.record_interval(self.cluster, cont,
-                                 int(tt.active_mask().sum()), predicted,
-                                 interval_overhead)
-        self._update_job_completion()
-        self.t += 1
+        with span("sim.record", t=t):
+            cont = M.contention_metric(self.cluster, tt.view("req"),
+                                       tt.view("host"), tt.active_mask())
+            self.log.record_interval(self.cluster, cont,
+                                     int(tt.active_mask().sum()), predicted,
+                                     interval_overhead)
+            self._update_job_completion()
+            self.t += 1
 
     def run(self) -> dict:
         for _ in range(self.cfg.n_intervals):

@@ -22,6 +22,7 @@ from repro.core.start import STARTController
 from repro.policy import (Action, EVENT_INTERVAL, Policy, PretrainContext,
                           TelemetryView, register)
 from repro.sim.config import SimConfig
+from repro.trace import span
 
 
 def _host_matrix(view: TelemetryView) -> np.ndarray:
@@ -161,6 +162,10 @@ class START(Policy):
         return self._controller
 
     def observe(self, view: TelemetryView) -> None:
+        with span("start.observe", t=view.t):
+            self._observe(view)
+
+    def _observe(self, view: TelemetryView) -> None:
         ctrl = self._ensure_controller(view)
         # task-attributable utilization: the guard/k adaptation should
         # respond to load that mitigation competes with, not the static
@@ -174,7 +179,9 @@ class START(Policy):
         # aggressively when the cluster has headroom, conservatively when
         # it is loaded.
         ctrl.predictor.k = self.k_lo + (self.k_hi - self.k_lo) * self._util
-        ctrl.observe_hosts(_host_matrix(view))
+        with span("start.host_features", t=view.t):
+            m_h = _host_matrix(view)
+        ctrl.observe_hosts(m_h)
         # ground-truth MA update from jobs completed so far (the engine
         # keeps the 0.8-decay moving average)
         ctrl.observe_straggler_counts(view.straggler_ma)
@@ -194,6 +201,10 @@ class START(Policy):
     def decide(self, view: TelemetryView) -> list[Action]:
         if view.event != EVENT_INTERVAL:
             return []
+        with span("start.decide", t=view.t):
+            return self._decide(view)
+
+    def _decide(self, view: TelemetryView) -> list[Action]:
         ctrl = self._ensure_controller(view)
         active = view.jobs.active()
         if len(active) == 0:
@@ -204,8 +215,9 @@ class START(Policy):
         # open_count incomplete original tasks, so open_count IS the
         # remaining-task count the Algorithm-1 trigger compares against);
         # per-job task-id lists are built only for triggered jobs
-        mts = _task_matrices(view, active)
-        q = np.asarray(view.jobs.count[active], np.float32)
+        with span("start.task_features", t=view.t):
+            mts = _task_matrices(view, active)
+            q = np.asarray(view.jobs.count[active], np.float32)
 
         def incomplete(job: int):
             # (tids, hosts, slots) — the third element maps each open
@@ -224,9 +236,15 @@ class START(Policy):
             active, mts, q, view.jobs.open_count[active],
             view.jobs.deadline[active], incomplete, host_load=load)
         self._last_es_sum = ctrl.es_total(int(j) for j in active)
-        # expected-benefit guard: a re-execution starts from zero progress,
-        # so it only helps when  work/eff(target) < remaining/eff(source)
-        # with the utilization-scaled, kind-aware margin (class docstring)
+        with span("start.guard", t=view.t):
+            return self._guard(view, acts)
+
+    def _guard(self, view: TelemetryView, acts) -> list[Action]:
+        """Expected-benefit guard: a re-execution starts from zero
+        progress, so it only helps when  work/eff(target) <
+        remaining/eff(source)  with the utilization-scaled, kind-aware
+        margin (class docstring)."""
+        h = view.hosts
         eff = h.effective_speed()
         tt = view.tasks
         out = []
