@@ -114,23 +114,18 @@ def test_per_task_scores_decompose_es():
 # ------------------- warm path: zero retraces / zero H2D --------------------
 
 def test_warm_per_task_cell_zero_retraces_and_zero_transfers(
-        overload_ctrl_bytes, monkeypatch):
+        overload_ctrl_bytes, sanction_uploads):
     """A warm start-eager cell — the per-task head enabled on every
     predicted interval — must never recompile a prediction program and
-    must perform no host->device transfer beyond the fused step's single
-    staged upload."""
+    must perform no host->device transfer beyond the fused step's one
+    upload, which is the launch's own staging argument (``_launch``);
+    ``_stage`` uploads only ring rebuilds and catch-up rows."""
     ctrl_bytes, spec = overload_ctrl_bytes
     cfg = spec.cell_config("overload", 0)
     warm = STARTEager(controller=pickle.loads(ctrl_bytes))
     Simulation(cfg, technique=warm).run()          # warm all buckets
 
-    orig_stage = StragglerPredictor._stage
-
-    def sanctioned_stage(self, arr):
-        with jax.transfer_guard_host_to_device("allow"):
-            return orig_stage(self, arr)
-
-    monkeypatch.setattr(StragglerPredictor, "_stage", sanctioned_stage)
+    calls = sanction_uploads()
     tech = STARTEager(controller=pickle.loads(ctrl_bytes))
     compiles_before = (net.predict_sequence._cache_size()
                        + fused_compile_count())
@@ -143,6 +138,9 @@ def test_warm_per_task_cell_zero_retraces_and_zero_transfers(
     pred = tech._controller.predictor
     assert pred.h2d_stages > 0
     assert pred.h2d_stages <= cfg.n_intervals + 1
+    assert calls["_launch"] == pred.fused_calls > 0
+    assert calls["_stage"] == pred.ring_rebuilds + pred.catchup_rolls
+    assert pred.h2d_stages == calls["_launch"] + calls["_stage"]
 
 
 # ------------------------- non-finite E_S guard -----------------------------

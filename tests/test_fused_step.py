@@ -10,8 +10,9 @@ Pins the fused path's contracts under the tiered determinism model:
     deterministic — a full planetlab x start cell reproduces bitwise
     across runs and across pickling;
   * a warm interval performs **zero XLA retraces and zero host->device
-    transfers** beyond its single staged upload (that guarantee is hard,
-    not toleranced);
+    transfers** beyond its single upload, the packed staging vector that
+    the launch takes as its own argument (that guarantee is hard, not
+    toleranced);
   * the sweep's parent-pretrain broadcast and the parent-participating
     scheduler preserve serial == parallel bitwise while removing the
     per-worker duplicate pretraining.
@@ -26,7 +27,8 @@ import pytest
 
 from repro.core import encoder_lstm as net
 from repro.core import features
-from repro.core.predictor import StragglerPredictor, fused_compile_count
+from repro.core.predictor import (StragglerPredictor, _fused_step,
+                                  _stage_put, fused_compile_count)
 from repro.core.start import STARTController
 from repro.sim import sweep
 from repro.sim.engine import Simulation
@@ -142,24 +144,20 @@ def test_fused_predictor_survives_pickling_mid_run():
 # ------------------- zero retraces / zero transfers warm -------------------
 
 def test_warm_intervals_zero_retraces_and_zero_transfers(
-        trained_start_bytes, monkeypatch):
+        trained_start_bytes, sanction_uploads):
     """After a cell has warmed every bucket, further cells must (a) never
     recompile a prediction program and (b) perform no host->device
-    transfer per interval beyond the fused step's single staged upload —
-    pinned by running a whole warm cell under
-    ``jax.transfer_guard_host_to_device('disallow')`` with only the
-    predictor's ``_stage`` uploads exempted."""
+    transfer per interval beyond the fused step's one upload, which is the
+    launch's own staging argument — pinned by running a whole warm cell
+    under ``jax.transfer_guard_host_to_device('disallow')`` with only the
+    predictor's funnels exempted: ``_launch`` (the warm interval) and
+    ``_stage`` (ring rebuilds and catch-up rows, never a warm interval's
+    upload)."""
     tech_bytes, cfg = trained_start_bytes
     warm = pickle.loads(tech_bytes)
     Simulation(cfg, technique=warm).run()          # warm all buckets
 
-    orig_stage = StragglerPredictor._stage
-
-    def sanctioned_stage(self, arr):
-        with jax.transfer_guard_host_to_device("allow"):
-            return orig_stage(self, arr)
-
-    monkeypatch.setattr(StragglerPredictor, "_stage", sanctioned_stage)
+    calls = sanction_uploads()
     tech = pickle.loads(tech_bytes)
     compiles_before = (net.predict_sequence._cache_size()
                        + fused_compile_count())
@@ -170,10 +168,67 @@ def test_warm_intervals_zero_retraces_and_zero_transfers(
             - compiles_before)
     assert grew == 0, "warm cell retraced a prediction program"
     pred = tech._controller.predictor
-    # one staged upload per predicted interval (ring rebuilds after
-    # unpickling add their one-time upload through the same funnel)
+    # one upload per predicted interval, carried by its launch (ring
+    # rebuilds after unpickling add their one-time upload through _stage)
     assert pred.h2d_stages <= cfg.n_intervals + 1
+    assert calls["_launch"] == pred.fused_calls > 0
+    assert calls["_stage"] == pred.ring_rebuilds + pred.catchup_rolls
+    assert pred.h2d_stages == calls["_launch"] + calls["_stage"]
     assert pred.h2d_stages > 0
+
+
+# ------------- the staging vector as the launch's own argument -------------
+
+def _staged_predictor(n, rng, n_hosts=4, max_tasks=3):
+    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks)
+    for _ in range(pred.horizon):
+        pred.push_host_row(rng.uniform(
+            0, 1, (n_hosts, features.HOST_FEATURES)).astype(np.float32))
+    m_t = rng.uniform(0, 1, (n, max_tasks, features.TASK_FEATURES)) \
+        .astype(np.float32)
+    q = rng.integers(1, max_tasks + 1, n).astype(np.float32)
+    return pred, m_t, q
+
+
+@pytest.mark.parametrize("per_task", [False, True])
+@pytest.mark.parametrize("n", [1, 6])
+def test_launch_argument_is_bitwise_the_staged_upload(n, per_task):
+    """Handing the packed numpy staging vector to ``_fused_step`` as its
+    own argument is the same program on the same bytes as uploading it
+    first through ``_stage_put``: E_S, the per-task scores and the new
+    ring are bitwise equal."""
+    pred, m_t, q = _staged_predictor(n, np.random.default_rng(n))
+    pred.predict_interval(m_t, q)                 # builds the ring
+    nb = pred.batch_size(n)
+    buf = pred._pack(pred._row_hist[-1], m_t, q, n, nb).copy()
+    ring = np.asarray(pred._ring)
+    kw = dict(nb=nb, task_dim=pred.task_dim, per_task=per_task,
+              use_pallas=pred.use_pallas_cell, unroll=pred._unroll(nb))
+    got = _fused_step(pred.params, _stage_put(ring), buf, **kw)
+    want = _fused_step(pred.params, _stage_put(ring), _stage_put(buf), **kw)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("operand", ["params", "ring"])
+def test_sanctioned_launch_refuses_host_operands(operand, sanction_uploads):
+    """The zero-transfer tests let ``_launch`` upload under the guard, so
+    the sanction itself must refuse a launch whose params or ring sit in
+    host memory (a numpy leaf would be uploaded on every interval)."""
+    rng = np.random.default_rng(3)
+    pred, m_t, q = _staged_predictor(2, rng)
+    pred.predict_interval(m_t, q)                 # warm: ring on device
+    pred.push_host_row(rng.uniform(
+        0, 1, (pred.n_hosts, features.HOST_FEATURES)).astype(np.float32))
+    if operand == "params":
+        pred.params = jax.tree_util.tree_map(np.asarray, pred.params)
+    else:
+        pred._ring = np.asarray(pred._ring)
+    calls = sanction_uploads()
+    with jax.transfer_guard_host_to_device("disallow"):
+        with pytest.raises(AssertionError, match="host operands"):
+            pred.predict_interval(m_t, q)
+    assert calls["_launch"] == 1
 
 
 # --------------------- pallas-cell training route exact ---------------------

@@ -260,12 +260,12 @@ def _round(svc, tenants, rng, seq, m_t):
     return ps
 
 
-def test_interleaved_tenants_zero_warm_retraces(monkeypatch):
+def test_interleaved_tenants_zero_warm_retraces(sanction_uploads):
     """Interleaved multi-tenant traffic must reuse the power-of-two
     bucket cache: after each tenant-count pattern has run once, further
-    ticks compile nothing and upload only through ``_stage`` — pinned
-    under ``transfer_guard('disallow')`` exactly like the fused-step
-    test."""
+    ticks compile nothing and upload only through ``_stage`` and the
+    single-tenant fused launch (``_launch``) — pinned under
+    ``transfer_guard('disallow')`` exactly like the fused-step test."""
     svc = PredictionService(ServiceConfig(profile=profile()))
     rng = np.random.default_rng(11)
     tenants = [f"t{i}" for i in range(4)]
@@ -278,13 +278,7 @@ def test_interleaved_tenants_zero_warm_retraces(monkeypatch):
         _round(svc, group, rng, seq, m_t)
         seq += 1
 
-    orig = StragglerPredictor._stage
-
-    def sanctioned(self, arr):
-        with jax.transfer_guard_host_to_device("allow"):
-            return orig(self, arr)
-
-    monkeypatch.setattr(StragglerPredictor, "_stage", sanctioned)
+    calls = sanction_uploads()
     before = compile_counters()
     with jax.transfer_guard_host_to_device("disallow"):
         for group in (tenants[:3], [tenants[1]], tenants, tenants[:2],
@@ -293,6 +287,7 @@ def test_interleaved_tenants_zero_warm_retraces(monkeypatch):
             seq += 1
     assert compile_counters() - before == 0, \
         "warm multi-tenant tick retraced a prediction program"
+    assert calls["_launch"] > 0 and calls["_stage"] > 0
 
 
 def test_multi_tenant_matches_single_tenant_answers():
