@@ -215,7 +215,28 @@ def encoder_hoisted(params: Params, mh_ema: jax.Array,
     host_dim = mh_ema.shape[-1]
     lam_h = _mm(mh_ema, l0["w"][:host_dim])         # (T, E) — once per step
     lam_t = _mm(mt, l0["w"][host_dim:]) + l0["b"]   # (nb, E) — once per job
-    h = jax.nn.softplus(lam_h[:, None, :] + lam_t[None, :, :])
+    return _encoder_rest(params, lam_h[:, None, :] + lam_t[None, :, :])
+
+
+def encoder_segmented(params: Params, mh_ema: jax.Array, mt: jax.Array,
+                      seg: jax.Array) -> jax.Array:
+    """:func:`encoder_hoisted` for job rows of many host blocks (the
+    tenant batch): ``mh_ema`` is (T, G, host_dim), one EMA-smoothed host
+    window per tenant, ``seg`` (nb,) the block of each of the (nb,
+    task_dim) task rows.  Each block's host product is computed once per
+    step and gathered to its rows, so no row carries a copy of a host
+    window.  Returns the (T, nb, ENC_OUT) encodings."""
+    l0 = params["enc"][0]
+    host_dim = mh_ema.shape[-1]
+    lam_h = _mm(mh_ema, l0["w"][:host_dim])         # (T, G, E)
+    lam_t = _mm(mt, l0["w"][host_dim:]) + l0["b"]   # (nb, E)
+    return _encoder_rest(params, jnp.take(lam_h, seg, axis=1) + lam_t[None])
+
+
+def _encoder_rest(params: Params, pre: jax.Array) -> jax.Array:
+    """The first layer's activation over its summed pre-activation, then
+    the encoder's later layers."""
+    h = jax.nn.softplus(pre)
     for layer in params["enc"][1:]:
         h = jax.nn.softplus(_mm(h, layer["w"]) + layer["b"])
     return h
@@ -239,23 +260,6 @@ def decode_sequence(params: Params, lam: jax.Array, unroll: int = 1,
 
     _, outs = jax.lax.scan(f, state, lam, unroll=unroll)
     return outs[-1]
-
-
-@functools.partial(jax.jit, static_argnames=("unroll", "use_pallas"))
-def predict_sequence_opt(params: Params, xs: jax.Array, unroll: int = 1,
-                         use_pallas: bool = False) -> jax.Array:
-    """Tier-1 twin of :func:`predict_sequence` for callers whose host
-    blocks vary per row (the multi-tenant serving batch): the encoder
-    runs batched over the whole (T, nb) grid — one matmul chain instead
-    of one per scan step — and the LSTM scan unrolls.  No host/task
-    split (rows carry different host blocks), so the only drift sources
-    are batched-encoder fusion and ``unroll``."""
-    xs = ema_smooth(xs)
-    lam = xs
-    for layer in params["enc"]:
-        lam = jax.nn.softplus(_mm(lam, layer["w"]) + layer["b"])
-    return decode_sequence(params, lam, unroll=unroll,
-                           use_pallas=use_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))

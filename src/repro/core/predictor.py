@@ -66,8 +66,8 @@ def bucket_size(n: int) -> int:
     return max(1 << (int(n) - 1).bit_length(), 1) if n else 1
 
 
-# uploads outside the warm interval (ring rebuilds, catch-up rows, the
-# tenant batch) ride the pjit fast path (see StragglerPredictor._stage)
+# uploads outside the warm interval (ring rebuilds, catch-up rows, tenant
+# slot rebuilds) ride the pjit fast path (see StragglerPredictor._stage)
 _stage_put = jax.jit(lambda x: x)
 
 
@@ -121,6 +121,17 @@ def _fused_step(params, ring, packed, *, nb: int, task_dim: int,
     ring2 = jnp.concatenate([ring[1:], row[None]], axis=0)
     mh_ema = net.ema_smooth(ring2)                        # (T, host_dim)
     lam = net.encoder_hoisted(params, mh_ema, mt)         # (T, nb, E)
+    return ring2, _decode_tail(params, lam, k, beta_scale, q, mt, nb=nb,
+                               task_dim=task_dim, use_pallas=use_pallas,
+                               per_task=per_task, unroll=unroll)
+
+
+def _decode_tail(params, lam, k, beta_scale, q, mt, *, nb, task_dim,
+                 use_pallas, per_task, unroll):
+    """The fused programs' common end: the LSTM scan over the encodings
+    ``lam``, the Pareto tail to E_S, and with ``per_task`` the per-task
+    scores (packed ``[E_S | scores]``, as :func:`_pareto_tail_per_task`
+    packs them)."""
     ab = net.decode_sequence(params, lam, unroll=unroll,
                              use_pallas=use_pallas)
     alpha = ab[..., 0]
@@ -139,8 +150,69 @@ def _fused_step(params, ring, packed, *, nb: int, task_dim: int,
                           demand / jnp.where(total > 0.0, total, 1.0),
                           uniform)
         scores = e_s[:, None] * share
-        return ring2, jnp.concatenate([e_s[:, None], scores], axis=1)
-    return ring2, e_s
+        return jnp.concatenate([e_s[:, None], scores], axis=1)
+    return e_s
+
+
+# ----------------------- tenant-batched interval step -----------------------
+#
+# The service's tick: many tenants' intervals in one launch.  Their host
+# windows live on the device in a slab of tenant rings, (slots, T,
+# host_dim), one slot per admitted tenant.  Packed staging layout, one
+# float32 vector per tick handed to the launch as its own argument:
+# [k, beta_scale, present(slots), q(nb), row_slot(nb),
+#  new_mh_rows(slots*host_dim), m_t(nb*task_dim)].  ``present`` marks the
+# slots whose tenant is in the tick; ``row_slot`` is each job row's slot.
+
+
+def tenant_layout(n_slots: int, nb: int, host_dim: int,
+                  task_dim: int) -> tuple[int, ...]:
+    """Offsets of the tenant step's packed vector: where ``present``,
+    ``q``, ``row_slot``, the host rows and M_T begin, and its size."""
+    present = _N_SCALARS
+    q = present + n_slots
+    seg = q + nb
+    rows = seg + nb
+    mt = rows + n_slots * host_dim
+    return present, q, seg, rows, mt, mt + nb * task_dim
+
+
+@functools.partial(jax.jit, donate_argnums=(1,),
+                   static_argnames=("nb", "task_dim", "use_pallas",
+                                    "per_task", "unroll"))
+def _fused_step_tenants(params, slab, packed, *, nb: int, task_dim: int,
+                        use_pallas: bool | str = False,
+                        per_task: bool = False, unroll: int = 1):
+    """:func:`_fused_step` for many tenants at once (Tier-1): rolls each
+    present slot of the donated slab by its tenant's staged row (absent
+    slots keep their ring), encodes each slot's EMA window once
+    (``net.encoder_segmented``), gathers it to the slot's job rows, and
+    runs the scan and the tail over all rows.  Returns ``(new_slab,
+    out)`` with ``out`` as :func:`_fused_step` returns it."""
+    n_slots, _, host_dim = slab.shape
+    o_p, o_q, o_seg, o_rows, o_mt, _ = tenant_layout(n_slots, nb, host_dim,
+                                                     task_dim)
+    k, beta_scale = packed[0], packed[1]
+    present = packed[o_p:o_q] > 0.0
+    q = packed[o_q:o_seg]
+    seg = packed[o_seg:o_rows].astype(jnp.int32)
+    rows = packed[o_rows:o_mt].reshape(n_slots, 1, host_dim)
+    mt = packed[o_mt:].reshape(nb, task_dim)
+    rolled = jnp.concatenate([slab[:, 1:], rows], axis=1)
+    slab2 = jnp.where(present[:, None, None], rolled, slab)
+    mh_ema = net.ema_smooth(jnp.swapaxes(slab2, 0, 1))   # (T, slots, hd)
+    lam = net.encoder_segmented(params, mh_ema, mt, seg)  # (T, nb, E)
+    return slab2, _decode_tail(params, lam, k, beta_scale, q, mt, nb=nb,
+                               task_dim=task_dim, use_pallas=use_pallas,
+                               per_task=per_task, unroll=unroll)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _slot_write(slab, packed):
+    """Rebuild one slot of the tenant slab: ``packed`` is ``[slot,
+    window(T*host_dim)]``, the window its tenant's host history holds."""
+    slot = packed[0].astype(jnp.int32)
+    return slab.at[slot].set(packed[1:].reshape(slab.shape[1:]))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -153,11 +225,11 @@ def _ring_roll(ring, row):
 def fused_compile_count() -> int:
     """Cumulative XLA compiles of the fused-step programs (process-wide):
     the fused step itself (Pareto tail and per-task head now live inside
-    it), the ring catch-up roll, the serving batch path's optimized
-    sequence program, and the unfused per-task tail — the zero-retrace
-    warm accounting covers every Tier-1 entry point."""
+    it), the ring catch-up roll, the service's tenant step and its slot
+    rebuild, and the unfused per-task tail — the zero-retrace warm
+    accounting covers every Tier-1 entry point."""
     return (_fused_step._cache_size() + _ring_roll._cache_size()
-            + net.predict_sequence_opt._cache_size()
+            + _fused_step_tenants._cache_size() + _slot_write._cache_size()
             + _pareto_tail_per_task._cache_size())
 
 
@@ -284,14 +356,22 @@ class StragglerPredictor:
         #: truth the device ring is rebuilt from (cold start, unpickling,
         #: error recovery)
         self._row_hist = collections.deque(maxlen=self.horizon)
-        self._stage_bufs: dict[int, np.ndarray] = {}  # per-bucket staging
-        self._scalar_cache = None  # device (k, beta_scale) for serving
+        self._stage_bufs: dict = {}  # per-bucket staging vectors
         self.h2d_stages = 0        # host->device staging uploads performed
         self.fused_calls = 0       # fused-step dispatches
         self.catchup_rolls = 0     # idle-interval rows rolled in alone
         self.ring_rebuilds = 0     # rings rebuilt from the host history
         self.rows_real = 0         # real job rows over the fused calls
         self.rows_dispatched = 0   # batch rows over the fused calls
+        # the service's tenant batch (see open_slab)
+        self._slab = None          # device (slots, horizon, host_dim)
+        #: host rows each slot has absorbed (None: rebuild on next use)
+        self._slot_rows: list = []
+        self.tenant_calls = 0            # tenant-step launches
+        self.tenant_rows_real = 0        # real job rows over them
+        self.tenant_rows_dispatched = 0  # batch rows over them
+        self.tenant_slot_rebuilds = 0    # slots rebuilt from a history
+        self.tenant_stage_bytes = 0      # bytes of their launch arguments
 
     def __getstate__(self):
         # the device ring is a pure cache of `_row_hist`; drop it so
@@ -301,7 +381,8 @@ class StragglerPredictor:
         d["_ring"] = None
         d["_ring_rows"] = 0
         d["_stage_bufs"] = {}
-        d["_scalar_cache"] = None
+        d["_slab"] = None
+        d["_slot_rows"] = [None] * len(self._slot_rows)
         return d
 
     def push_host_row(self, m_h: np.ndarray) -> None:
@@ -313,7 +394,7 @@ class StragglerPredictor:
 
     def _stage(self, arr: np.ndarray) -> jax.Array:
         """A host->device upload outside the warm interval: ring rebuilds,
-        catch-up rows and the tenant batch's inputs.  Counted in
+        catch-up rows and tenant slot rebuilds.  Counted in
         ``h2d_stages`` with the warm interval's own upload (see
         :meth:`_launch`), and centralised so the zero-transfer tests can
         wrap it in a scoped ``jax.transfer_guard_host_to_device('allow')``
@@ -329,17 +410,18 @@ class StragglerPredictor:
         return _stage_put(arr)
 
     def _launch(self, ring: jax.Array, buf: np.ndarray, nb: int,
-                per_task: bool):
-        """Launch the fused step: the warm interval's single sanctioned
+                per_task: bool, step=None):
+        """Launch the fused step (or ``step``, the tenant step, over the
+        tenant slab as ``ring``): the warm interval's single sanctioned
         host->device upload, the packed staging vector ``buf`` handed to
         the launch as its own argument (no separate upload program).
         Counted in ``h2d_stages``; the zero-transfer tests wrap this call
         as they wrap :meth:`_stage`.  Returns ``(new_ring, out)``."""
         self.h2d_stages += 1
-        return _fused_step(self.params, ring, buf, nb=nb,
-                           task_dim=self.task_dim,
-                           use_pallas=self.use_pallas_cell,
-                           per_task=per_task, unroll=self._unroll(nb))
+        return (step or _fused_step)(
+            self.params, ring, buf, nb=nb, task_dim=self.task_dim,
+            use_pallas=self.use_pallas_cell, per_task=per_task,
+            unroll=self._unroll(nb))
 
     # ------------------------- Tier-1 batch shaping ------------------------
 
@@ -529,85 +611,106 @@ class StragglerPredictor:
 
     # ------------------------ multi-tenant serving -------------------------
 
-    def _scalars_dev(self) -> tuple[jax.Array, jax.Array]:
-        """Device-resident (k, beta_scale), cached per value — the
-        serving batch path must not re-upload scalar hyper-parameters
-        every tick (the transfer-guard accounting pins it)."""
-        key = (float(self.k), float(self.beta_scale))
-        cached = getattr(self, "_scalar_cache", None)
-        if cached is None or cached[0] != key:
-            cached = (key, (self._stage(np.float32(self.k)),
-                            self._stage(np.float32(self.beta_scale))))
-            self._scalar_cache = cached
-        return cached[1]
+    def open_slab(self, n_slots: int) -> None:
+        """Serve the tenant batch from ``n_slots`` tenant rings on the
+        device, every slot empty (its first use rebuilds it)."""
+        self._slab = None
+        self._slot_rows = [None] * n_slots
 
-    def predict_tenants(self, host_seqs: list, mt_list: list,
-                        q_list: list, per_task: bool = False) -> list:
-        """Multi-tenant batched prediction (the serving daemon's batch
-        tick): many small clusters share one device-resident model and
-        one network dispatch.
+    def forget_slot(self, slot: int) -> None:
+        """``slot`` changes hands: its next use rebuilds it."""
+        self._slot_rows[slot] = None
+
+    def _sync_slot(self, slot: int, rows_seen: int, hist) -> np.ndarray:
+        """Bring ``slot`` to all but the newest of the ``rows_seen`` host
+        rows its tenant has observed, and return that newest row for the
+        tenant step to roll in.  ``hist`` (the tenant's last ``horizon``
+        host matrices, oldest first) is the truth and the slot a cache of
+        it: a slot newly taken, lost, or behind by more than one row is
+        rebuilt from it (one upload), left-padded with its oldest row as
+        the controller pads."""
+        rows = list(hist)
+        if self._slab is None:
+            self._slab = jnp.zeros((len(self._slot_rows), self.horizon,
+                                    self.host_dim), jnp.float32)
+            self._slot_rows = [None] * len(self._slot_rows)
+        if self._slot_rows[slot] != rows_seen - 1:
+            win = rows[:-1] or rows[:1]
+            win = [win[0]] * (self.horizon - len(win)) + win
+            buf = np.empty(1 + self.horizon * self.host_dim, np.float32)
+            buf[0] = slot
+            buf[1:] = np.reshape(win, -1)
+            slab, self._slab = self._slab, None     # donated
+            self._slab = _slot_write(slab, self._stage(buf))
+            self.tenant_slot_rebuilds += 1
+        self._slot_rows[slot] = rows_seen
+        return rows[-1]
+
+    def pack_tenants(self, group: list) -> tuple[np.ndarray, int, int]:
+        """The tenant step's staging vector for one tick, after bringing
+        each tenant's slot up to date (:meth:`_sync_slot`).
 
         Args:
-            host_seqs: per-tenant ``(T, n_hosts, HOST_FEATURES)`` (or
-                pre-flattened ``(T, host_dim)``) host history windows,
-                ``T == horizon`` for every tenant.
-            mt_list: per-tenant ``(n_i, max_tasks, TASK_FEATURES)``
-                current task matrices.
-            q_list: per-tenant ``(n_i,)`` true task counts.
-            per_task: also return per-task scores.
+            group: per tenant of the tick ``(slot, rows_seen, hist, m_t,
+                q)``: its slot, the host rows it has observed, its host
+                history, its ``(n_i, max_tasks, TASK_FEATURES)`` task
+                matrices and ``(n_i,)`` task counts (``n_i`` may be 0).
 
-        The tenants' job axes are concatenated, each job row carries its
-        own tenant's host block, and the combined batch goes through
-        :meth:`batch_size` (power-of-two bucket, or the exact count when
-        padding would waste too much) — so the jitted network compiles
-        once per batch shape regardless of how tenants interleave, and a
-        warm tick is one dispatch.  Padded rows replicate the last
-        tenant's host block.  All uploads go through :meth:`_stage`.
+        Returns ``(buf, nb, n)``: the vector, the dispatched rows (the
+        power-of-two bucket of the ``n`` real ones; padding rows take the
+        last real row's slot, q 1 and a zero M_T).  Only new rows cross:
+        one host row per slot, and M_T, q and a slot index per row."""
+        n_slots = len(self._slot_rows)
+        n = sum(len(g[4]) for g in group)
+        nb = bucket_size(n)
+        o_p, o_q, o_seg, o_rows, o_mt, size = tenant_layout(
+            n_slots, nb, self.host_dim, self.task_dim)
+        buf = self._stage_bufs.get(("tenants", nb))
+        if buf is None or buf.shape[0] != size:
+            buf = self._stage_bufs[("tenants", nb)] = np.zeros(size,
+                                                               np.float32)
+        buf[0] = np.float32(self.k)
+        buf[1] = np.float32(self.beta_scale)
+        buf[o_p:o_q] = 0.0
+        rows = buf[o_rows:o_mt].reshape(n_slots, self.host_dim)
+        mt = buf[o_mt:].reshape(nb, self.task_dim)
+        lo, last = 0, group[-1][0]
+        try:
+            for slot, rows_seen, hist, m_t, q in group:
+                rows[slot] = self._sync_slot(slot, rows_seen,
+                                             hist).reshape(-1)
+                buf[o_p + slot] = 1.0
+                hi = lo + len(q)
+                if hi > lo:
+                    buf[o_q + lo:o_q + hi] = q
+                    buf[o_seg + lo:o_seg + hi] = last = slot
+                    mt[lo:hi] = np.reshape(m_t, (hi - lo, self.task_dim))
+                lo = hi
+        except BaseException:
+            self._slab = None       # a half-synced slab: rebuild them all
+            raise
+        buf[o_q + n:o_seg] = 1.0
+        buf[o_seg + n:o_rows] = last
+        mt[n:] = 0.0
+        return buf, nb, n
 
-        This is a **Tier-1** path: it runs the restructured
-        ``net.predict_sequence_opt`` emission (batched encoder, unrolled
-        scan), so results agree with the unfused reference within the
-        documented tolerance bound rather than bitwise — still fully
-        deterministic per (shape, unroll, platform).
-
-        Returns a list with one ``e_s`` array per tenant, or one
-        ``(e_s, scores)`` pair per tenant when ``per_task``.
-        """
-        t = self.horizon
-        host_dim = self.host_dim
-        ns = [int(m.shape[0]) for m in mt_list]
-        total = int(sum(ns))
-        nb = self.batch_size(total)
-        self.buckets_used.add(nb)
-        xs = np.zeros((t, nb, self.input_dim), np.float32)
-        qp = np.ones(nb, np.float32)
-        lo = 0
-        for seq, mt, q, n in zip(host_seqs, mt_list, q_list, ns):
-            hi = lo + n
-            mh_flat = np.asarray(seq, np.float32).reshape(t, 1, host_dim)
-            xs[:, lo:hi, :host_dim] = mh_flat
-            xs[:, lo:hi, host_dim:] = \
-                np.asarray(mt, np.float32).reshape(1, n, -1)
-            qp[lo:hi] = np.asarray(q, np.float32)
-            lo = hi
-        if total < nb and host_seqs:
-            xs[:, total:, :host_dim] = np.asarray(
-                host_seqs[-1], np.float32).reshape(t, 1, host_dim)
-        kd, bsd = self._scalars_dev()
-        ab = net.predict_sequence_opt(self.params, self._stage(xs),
-                                      unroll=self._unroll(nb),
-                                      use_pallas=self.use_pallas_cell)
-        if per_task:
-            out = np.asarray(_pareto_tail_per_task(
-                ab, self._stage(qp), kd, bsd,
-                self._stage(np.ascontiguousarray(
-                    xs[-1, :, host_dim:]))))
-            return [(out[lo:lo + n, 0], out[lo:lo + n, 1:])
-                    for lo, n in zip(np.cumsum([0] + ns[:-1]), ns)]
-        _, _, _, e_s = _pareto_tail(ab, self._stage(qp), kd, bsd)
-        e_s = np.asarray(e_s)
-        return [e_s[lo:lo + n]
-                for lo, n in zip(np.cumsum([0] + ns[:-1]), ns)]
+    def predict_tenants(self, buf: np.ndarray, nb: int, n: int, g: int,
+                        per_task: bool = False) -> np.ndarray:
+        """The service's tick (Tier-1): one launch of the tenant step on
+        the staging vector of :meth:`pack_tenants` (``n`` real rows of
+        ``g`` tenants), then one readback.  Returns E_S of the ``n``
+        rows in the packing's order, or ``(n, 1 + max_tasks)`` ``[E_S |
+        scores]`` when ``per_task``."""
+        with span("predictor.tenants", g=g, n=n, nb=nb):
+            slab, self._slab = self._slab, None     # donated
+            self.buckets_used.add(nb)
+            self._slab, out = self._launch(slab, buf, nb, per_task,
+                                           step=_fused_step_tenants)
+            self.tenant_calls += 1
+            self.tenant_rows_real += n
+            self.tenant_rows_dispatched += nb
+            self.tenant_stage_bytes += buf.nbytes
+            return np.asarray(out)[:n]
 
     # ---------------------------- inference -------------------------------
 

@@ -13,11 +13,19 @@ Dispatch per batch tick:
   * exactly one tenant queued -> that tenant's fused
     ``predict_interval`` path, bitwise-equal to calling the predictor
     in-process (the acceptance criterion);
-  * several tenants queued -> one combined
-    ``StragglerPredictor.predict_tenants`` dispatch: per-tenant host
-    blocks, all jobs coalesced into one power-of-two bucket, zero warm
-    retraces because every bucket/shape was compiled by the first tick
-    that used it.
+  * several tenants queued -> one tenant-batched device program
+    (``StragglerPredictor.predict_tenants``): each tenant's host window
+    lives in its slot of a device-resident slab of tenant rings (taken
+    at ``hello``, freed at ``bye``, rebuilt from the tenant's host
+    history when it falls behind), the tick uploads one new host row per
+    tenant and each job's M_T, q and slot, and all jobs share one
+    power-of-two bucket — zero warm retraces because every bucket was
+    compiled by the first tick that used it.
+
+Spans (``repro.trace``): ``service.submit`` around each snapshot's
+sanitize and enqueue; ``service.tick`` holding ``service.ingest``,
+``service.pack``, ``predictor.tenants`` (the launch and its readback)
+and ``service.apply`` (the triggers and the answers).
 
 Backpressure is shed-oldest per tenant: each tenant may hold at most
 ``queue_depth`` unanswered snapshots; the oldest is resolved with an
@@ -38,6 +46,7 @@ from collections import deque
 
 import numpy as np
 
+from repro.core import features
 from repro.core.pareto import fit_pareto_np
 from repro.core.predictor import StragglerPredictor, _pareto_tail, \
     bucket_size
@@ -47,6 +56,7 @@ from repro.policy.wire import action_to_wire
 from repro.service import retrain as rt
 from repro.service.protocol import Profile, error
 from repro.service.sanitize import TelemetryError, sanitize_snapshot
+from repro.trace import span
 from repro.train.checkpoint import VersionStore
 
 
@@ -99,9 +109,11 @@ class Pending:
 
 
 class TenantState:
-    def __init__(self, name: str, cfg: ServiceConfig, params) -> None:
+    def __init__(self, name: str, cfg: ServiceConfig, params,
+                 slot: int) -> None:
         p = cfg.profile
         self.name = name
+        self.slot = slot          # this tenant's ring in the tenant slab
         self.predictor = StragglerPredictor(
             n_hosts=p.n_hosts, max_tasks=p.max_tasks, k=p.k,
             horizon=p.horizon, beta_scale=p.beta_scale, seed=cfg.seed,
@@ -145,6 +157,8 @@ class PredictionService:
             beta_scale=cfg.profile.beta_scale, seed=cfg.seed,
             use_pallas_cell=cfg.use_pallas)
         self.params = self.model.params
+        self.model.open_slab(cfg.max_tenants)
+        self._free_slots = list(range(cfg.max_tenants))[::-1]
         self.model_version = 0
         self.degraded = False
         self._prev: list[tuple[int, object]] = []  # in-memory history
@@ -299,14 +313,18 @@ class PredictionService:
             if len(self.tenants) >= self.cfg.max_tenants:
                 return error("at-capacity",
                              f"max_tenants={self.cfg.max_tenants}")
+            slot = self._free_slots.pop()
+            self.model.forget_slot(slot)
             self.tenants[tenant] = TenantState(tenant, self.cfg,
-                                               self.params)
+                                               self.params, slot)
             return {"ok": True, "tenant": tenant, "rejoined": False,
                     "version": self.model_version}
 
     def bye(self, tenant: str) -> dict:
         with self.lock:
             t = self.tenants.pop(tenant, None)
+            if t is not None:
+                self._free_slots.append(t.slot)
             for p in [p for p in self.pending if p.tenant == tenant]:
                 self.pending.remove(p)
                 p.resolve(error("gone", "tenant said bye"))
@@ -319,7 +337,7 @@ class PredictionService:
         snapshot resolves immediately with its error and touches no
         shared state."""
         p = Pending(tenant, snap)
-        with self.lock:
+        with self.lock, span("service.submit"):
             t = self.tenants.get(tenant)
             if t is None:
                 p.resolve(error("not-admitted",
@@ -385,15 +403,17 @@ class PredictionService:
             self.pending = keep
             if not batch:
                 return 0
-            self.stats_counters["ticks"] += 1
-            for p in batch:
-                self._ingest(self.tenants[p.tenant], p.snap)
-            results = self._answer(batch)
-            for p, res in zip(batch, results):
-                t = self.tenants.get(p.tenant)
-                if t is not None:
-                    t.last_answer = (p.snap["seq"], res)
-                p.resolve(res)
+            with span("service.tick", g=len(batch)):
+                self.stats_counters["ticks"] += 1
+                with span("service.ingest", g=len(batch)):
+                    for p in batch:
+                        self._ingest(self.tenants[p.tenant], p.snap)
+                results = self._answer(batch)
+                for p, res in zip(batch, results):
+                    t = self.tenants.get(p.tenant)
+                    if t is not None:
+                        t.last_answer = (p.snap["seq"], res)
+                    p.resolve(res)
             self._since_retrain += len(batch)
             if (self.cfg.retrain_every
                     and self._since_retrain >= self.cfg.retrain_every):
@@ -432,28 +452,33 @@ class PredictionService:
             p, t = with_jobs[0]
             preds[p.tenant] = self._predict_single(t, p.snap, per_task)
         elif with_jobs:
-            self._predict_many(with_jobs, per_task, preds)
+            self._predict_many(live, per_task, preds)
         out = []
-        for p, t in live:
-            jobs_out = []
-            if p.snap["jobs"]:
-                e_s, scores, actions = preds[p.tenant]
-                for i, j in enumerate(p.snap["jobs"]):
-                    entry = {"id": j["id"], "e_s": float(e_s[i])}
-                    if scores is not None:
-                        entry["scores"] = [
-                            float(x)
-                            for x in scores[i][:int(j["q"])]]
-                    entry["actions"] = [
-                        _mit_to_wire(a) for a in actions
-                        if a.job_id == j["id"]]
-                    jobs_out.append(entry)
-            self.stats_counters["batch_rows"] += len(jobs_out)
-            out.append({"ok": True, "seq": p.snap["seq"],
-                        "version": self.model_version,
-                        "degraded": bool(self.degraded),
-                        "sanitized": p.snap["issues"],
-                        "jobs": jobs_out})
+        with span("service.apply", g=len(batch)):
+            for p, t in live:
+                jobs_out = []
+                if p.snap["jobs"]:
+                    e_s, scores = preds[p.tenant]
+                    ids = np.array([j["id"] for j in p.snap["jobs"]],
+                                   np.int64)
+                    actions = self._apply(t, p.snap, ids, e_s, scores,
+                                          per_task)
+                    for i, j in enumerate(p.snap["jobs"]):
+                        entry = {"id": j["id"], "e_s": float(e_s[i])}
+                        if scores is not None:
+                            entry["scores"] = [
+                                float(x)
+                                for x in scores[i][:int(j["q"])]]
+                        entry["actions"] = [
+                            _mit_to_wire(a) for a in actions
+                            if a.job_id == j["id"]]
+                        jobs_out.append(entry)
+                self.stats_counters["batch_rows"] += len(jobs_out)
+                out.append({"ok": True, "seq": p.snap["seq"],
+                            "version": self.model_version,
+                            "degraded": bool(self.degraded),
+                            "sanitized": p.snap["issues"],
+                            "jobs": jobs_out})
         return out
 
     @staticmethod
@@ -482,41 +507,44 @@ class PredictionService:
         ids = np.array([j["id"] for j in jobs], np.int64)
         m_t = np.stack([j["m_t"] for j in jobs])
         q = np.array([j["q"] for j in jobs], np.float32)
-        ctrl = t.controller
         if per_task:
-            e_s, scores = ctrl.predict_scores_batch(ids, m_t, q)
-        else:
-            e_s = ctrl.predict_es_batch(ids, m_t, q)
-            scores = None
-        actions = self._apply(t, snap, ids, e_s, scores, per_task)
-        return e_s, scores, actions
+            return t.controller.predict_scores_batch(ids, m_t, q)
+        return t.controller.predict_es_batch(ids, m_t, q), None
 
-    def _predict_many(self, with_jobs: list, per_task: bool,
+    def _predict_many(self, live: list, per_task: bool,
                       preds: dict) -> None:
-        """One combined dispatch over every queued tenant's jobs."""
-        host_seqs, mt_list, q_list, metas = [], [], [], []
-        for p, t in with_jobs:
-            jobs = p.snap["jobs"]
-            host_seqs.append(t.controller._host_seq().reshape(
-                self.profile.horizon, -1))
-            mt_list.append(np.stack([j["m_t"] for j in jobs]).reshape(
-                len(jobs), -1))
-            q_list.append(np.array([j["q"] for j in jobs], np.float32))
-            metas.append((p, t, np.array([j["id"] for j in jobs],
-                                         np.int64)))
-        res = self.model.predict_tenants(host_seqs, mt_list, q_list,
+        """One tenant-batched launch over every tenant of the tick: each
+        tenant's slot rolls its new host row, and the rows of the tenants
+        with jobs are predicted."""
+        mt_shape = (0, self.profile.max_tasks, features.TASK_FEATURES)
+        with span("service.pack", g=len(live)):
+            group = []
+            for p, t in live:
+                jobs = p.snap["jobs"]
+                m_t = (np.stack([j["m_t"] for j in jobs]) if jobs
+                       else np.zeros(mt_shape, np.float32))
+                q = np.array([j["q"] for j in jobs], np.float32)
+                group.append((t.slot, t.snapshots,
+                              t.controller._host_hist, m_t, q))
+            buf, nb, n = self.model.pack_tenants(group)
+        out = self.model.predict_tenants(buf, nb, n, len(group),
                                          per_task=per_task)
-        for (p, t, ids), q, r in zip(metas, q_list, res):
+        lo = 0
+        for (p, t), (_, _, _, _, q) in zip(live, group):
+            hi = lo + len(q)
+            if hi == lo:
+                continue
             if per_task:
-                e_s, scores = r
-                scores = np.where(np.isfinite(scores), scores, 0.0)
+                e_s = out[lo:hi, 0]
+                scores = np.where(np.isfinite(out[lo:hi, 1:]),
+                                  out[lo:hi, 1:], 0.0)
             else:
-                e_s, scores = r, None
+                e_s, scores = out[lo:hi], None
             e_s = STARTController._sanitize_es(e_s, q)
-            for j, e in zip(ids, e_s):
-                t.controller._es_cache[int(j)] = float(e)
-            actions = self._apply(t, p.snap, ids, e_s, scores, per_task)
-            preds[p.tenant] = (e_s, scores, actions)
+            for j, e in zip(p.snap["jobs"], e_s):
+                t.controller._es_cache[j["id"]] = float(e)
+            preds[p.tenant] = (e_s, scores)
+            lo = hi
 
     def _degraded_predict(self, t: TenantState, snap: dict):
         """No model: jitted ``_pareto_tail`` over the tenant's own MLE
@@ -550,8 +578,7 @@ class PredictionService:
                 scores[i, :int(q[i])] = e_s[i] / max(q[i], 1.0)
         for j, e in zip(ids, e_s):
             t.controller._es_cache[int(j)] = float(e)
-        actions = self._apply(t, snap, ids, e_s, scores, per_task)
-        return e_s, scores, actions
+        return e_s, scores
 
     # ------------------------------ dispatch ----------------------------
 

@@ -4,15 +4,16 @@ Three jobs:
   * the helper's semantics: ulp arithmetic, non-finite handling, and —
     property-tested — that the bound is *tight*: perturbations beyond
     ``TIER1_REL`` fail, perturbations comfortably inside pass;
-  * the shape-sweep regression: every Tier-1 optimization (batched
-    encoder, scan unroll, split-encoder hoisting + fused Pareto tail,
-    exact-shape batches) pinned against the Tier-0 reference at every
-    swept shape, with the worst observed ulp drift pinned so growth is
+  * the shape-sweep regression: every Tier-1 optimization (split-encoder
+    hoisting + fused Pareto tail, exact-shape batches, the tenant step's
+    segmented encoder at each scan unroll) pinned against the Tier-0
+    reference at every swept shape, with the worst observed ulp drift pinned so growth is
     visible in review;
   * the Tier-0 firewall: the bitwise path (engine, sweep, golden
     fixture) must never import the tolerance helper — Tier-0 has no
     tolerances.
 """
+import collections
 import os
 import subprocess
 import sys
@@ -22,7 +23,6 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import encoder_lstm as net
 from repro.core import features
 from repro.core.predictor import StragglerPredictor
 
@@ -108,56 +108,6 @@ def test_sweep_drift_aggregates_worst_pair():
 _COUNTS = (1, 2, 3, 5, 8, 9, 12, 16)
 
 
-def _ref_and_opt_network(n_hosts=6, max_tasks=5, seed=0):
-    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks,
-                              seed=seed)
-    rng = np.random.default_rng(seed)
-    t = pred.horizon
-    mh = rng.uniform(0, 1, (t, n_hosts, features.HOST_FEATURES)) \
-        .astype(np.float32)
-    return pred, rng, mh
-
-
-def _xs_batch(pred, rng, mh, n):
-    xs = np.zeros((pred.horizon, n, pred.input_dim), np.float32)
-    xs[:, :, :pred.host_dim] = mh.reshape(pred.horizon, 1, -1)
-    xs[:, :, pred.host_dim:] = rng.uniform(
-        0, 1, (n, pred.task_dim)).astype(np.float32)[None]
-    return xs
-
-
-def test_shape_sweep_batched_encoder_within_bound():
-    """predict_sequence_opt(unroll=1) vs predict_sequence isolates the
-    batched-encoder fusion (encoder applied over (T, nb) at once instead
-    of per scan step)."""
-    pred, rng, mh = _ref_and_opt_network()
-    pairs = []
-    for n in _COUNTS:
-        xs = _xs_batch(pred, rng, mh, n)
-        ref = np.asarray(net.predict_sequence(pred.params, xs))
-        opt = np.asarray(net.predict_sequence_opt(pred.params, xs,
-                                                  unroll=1))
-        pairs.append((opt, ref))
-    worst = sweep_drift(pairs)
-    assert worst["max_ulp"] <= TIER1_MAX_ULP
-
-
-def test_shape_sweep_unroll_within_bound():
-    """Full unroll vs unroll=1 of the same decode isolates the scan
-    unrolling (loop fusion changes FMA grouping at some shapes)."""
-    pred, rng, mh = _ref_and_opt_network()
-    pairs = []
-    for n in _COUNTS:
-        xs = _xs_batch(pred, rng, mh, n)
-        u1 = np.asarray(net.predict_sequence_opt(pred.params, xs,
-                                                 unroll=1))
-        uT = np.asarray(net.predict_sequence_opt(pred.params, xs,
-                                                 unroll=pred.horizon))
-        pairs.append((uT, u1))
-    worst = sweep_drift(pairs)
-    assert worst["max_ulp"] <= TIER1_MAX_ULP
-
-
 def _fused_vs_reference(exact_shapes: bool):
     """Warm fused intervals vs predict_features at every swept count;
     ``exact_shapes`` toggles the exact-shape batch policy so its drift
@@ -212,24 +162,47 @@ def test_shape_sweep_exact_shapes_within_bound():
     assert worst["max_ulp"] <= TIER1_MAX_ULP
 
 
-def test_shape_sweep_tenant_batch_within_bound():
-    """The serving batch path (predict_sequence_opt behind
-    predict_tenants) vs per-tenant reference predictions."""
+def _tenant_step_vs_reference(unroll: int):
+    """Ticks of the tenant step (three tenants in slots 0, 2, 3 of four,
+    one new host row each per tick, their rows summing to each swept
+    count) vs each tenant's Tier-0 prediction from its own window, left
+    padded while it holds fewer than ``horizon`` rows."""
     n_hosts, max_tasks = 6, 5
-    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks)
+    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks,
+                              unroll=unroll)
+    pred.open_slab(4)
     rng = np.random.default_rng(11)
     t = pred.horizon
-    seqs, mts, qs = [], [], []
-    for n in (3, 1, 4, 2):
-        seqs.append(rng.uniform(
-            0, 1, (t, n_hosts, features.HOST_FEATURES)).astype(np.float32))
-        mts.append(rng.uniform(
-            0, 1, (n, max_tasks, features.TASK_FEATURES)).astype(np.float32))
-        qs.append(rng.integers(1, max_tasks + 1, n).astype(np.float32))
-    outs = pred.predict_tenants(seqs, mts, qs)
-    pairs = [(e, np.asarray(pred.predict_features(s, m, q).e_s))
-             for e, s, m, q in zip(outs, seqs, mts, qs)]
-    worst = sweep_drift(pairs)
+    hists = {slot: collections.deque(maxlen=t) for slot in (0, 2, 3)}
+    pairs = []
+    for tick, n in enumerate(_COUNTS):
+        group = []
+        for (slot, hist), c in zip(hists.items(),
+                                   (n - 2 * (n // 3), n // 3, n // 3)):
+            hist.append(rng.uniform(0, 1, (n_hosts, features.HOST_FEATURES))
+                        .astype(np.float32))
+            mt = rng.uniform(0, 1, (c, max_tasks, features.TASK_FEATURES)) \
+                .astype(np.float32)
+            q = rng.integers(1, max_tasks + 1, c).astype(np.float32)
+            group.append((slot, tick + 1, hist, mt, q))
+        buf, nb, m = pred.pack_tenants(group)
+        out = pred.predict_tenants(buf, nb, m, len(group))
+        lo = 0
+        for _, _, hist, mt, q in group:
+            if len(q):
+                win = [hist[0]] * (t - len(hist)) + list(hist)
+                ref = pred.predict_features(np.stack(win), mt, q)
+                pairs.append((out[lo:lo + len(q)], np.asarray(ref.e_s)))
+            lo += len(q)
+    return sweep_drift(pairs)
+
+
+@pytest.mark.parametrize("unroll", [1, 2, 5])
+def test_shape_sweep_tenant_step_within_bound(unroll):
+    """The service's tenant step (segmented encoder over the slab's
+    windows, unrolled scan, fused tail) vs per-tenant reference
+    predictions, at every swept row count and each unroll factor."""
+    worst = _tenant_step_vs_reference(unroll)
     assert worst["max_ulp"] <= TIER1_MAX_ULP
 
 

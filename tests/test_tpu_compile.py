@@ -19,10 +19,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import encoder_lstm as net
 from repro.core import features
-from repro.core.predictor import _N_SCALARS, _fused_step
+from repro.core.predictor import (_N_SCALARS, _fused_step,
+                                  _fused_step_tenants, _slot_write,
+                                  tenant_layout)
 from repro.kernels.lstm_cell.lstm_cell import lstm_cell_pallas
 
-N_HOSTS, MAX_TASKS, HORIZON = 400, 10, 5
+N_HOSTS, MAX_TASKS, HORIZON, SLOTS = 400, 10, 5, 64
 HOST_DIM = N_HOSTS * features.HOST_FEATURES
 TASK_DIM = MAX_TASKS * features.TASK_FEATURES
 INPUT_DIM = features.input_dim(N_HOSTS, MAX_TASKS)
@@ -90,12 +92,19 @@ def test_fused_step_compiles_at_table4_width(one_chip, nb, per_task,
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
-def test_tenant_batch_program_compiles_at_table4_width(one_chip,
-                                                       use_pallas):
-    compiled = net.predict_sequence_opt.lower(
-        _params(one_chip), _f32((HORIZON, 16, INPUT_DIM), one_chip),
-        unroll=2, use_pallas=use_pallas).compile()
+@pytest.mark.parametrize("nb", [2048, 4096])
+def test_tenant_step_compiles_at_service_width(one_chip, nb, use_pallas):
+    """The service's tenant step at the 64-tenant deployment: 64 slots of
+    400-host rings, the job rows of a tick up to 4096."""
+    slab = _f32((SLOTS, HORIZON, HOST_DIM), one_chip)
+    size = tenant_layout(SLOTS, nb, HOST_DIM, TASK_DIM)[-1]
+    compiled = _fused_step_tenants.lower(
+        _params(one_chip), slab, _f32((size,), one_chip), nb=nb,
+        task_dim=TASK_DIM, use_pallas=use_pallas, unroll=2).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
+    assert compiled.memory_analysis().argument_size_in_bytes < 16 << 20
+    _slot_write.lower(slab, _f32((1 + HORIZON * HOST_DIM,),
+                                 one_chip)).compile()
 
 
 def test_train_step_compiles_at_table4_width(one_chip):
