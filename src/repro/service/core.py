@@ -165,7 +165,8 @@ class PredictionService:
         self._retrain_due = False
         self._since_retrain = 0
         self.stats_counters = {
-            "snapshots": 0, "ticks": 0, "batch_rows": 0, "sheds": 0,
+            "snapshots": 0, "sanitize_bulk": 0, "ticks": 0,
+            "batch_rows": 0, "sheds": 0,
             "rejected": 0, "degraded_answers": 0, "retrains": 0,
             "promotions": 0, "rollbacks": 0, "candidates_rejected": 0,
             "retrain_failures": 0, "resends": 0, "auth_failures": 0,
@@ -365,7 +366,8 @@ class PredictionService:
                         return q
             try:
                 clean = sanitize_snapshot(snap, self.profile, t.last_seq,
-                                          mode=self.cfg.sanitize)
+                                          mode=self.cfg.sanitize,
+                                          counters=self.stats_counters)
             except TelemetryError as e:
                 self.stats_counters["rejected"] += 1
                 p.resolve(error(e.code, str(e)))

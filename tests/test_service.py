@@ -3,6 +3,7 @@ zero warm retraces, the boundary sanitizer, backpressure, and the
 versioned retrain/shadow-eval/rollback lifecycle."""
 import json
 import os
+from unittest import mock
 
 import jax
 import numpy as np
@@ -17,6 +18,7 @@ from repro.service import (LocalClient, PredictionService, Profile,
                            ServiceConfig, ServiceDaemon, TelemetryError,
                            sanitize_snapshot)
 from repro.service import retrain as svc_retrain
+from repro.service import sanitize as svc_sanitize
 from repro.train.checkpoint import VersionStore
 
 N_HOSTS, MAX_TASKS, HORIZON = 3, 4, 5
@@ -125,6 +127,202 @@ def test_sanitizer_rejects_out_of_order_and_structural():
     with pytest.raises(TelemetryError) as e:
         sanitize_snapshot(bad_q, profile(), -1.0)
     assert e.value.code == "bad-job"
+
+
+# ------------------------- bulk sanitizer path ---------------------------
+
+def wire_snap(rng, n_jobs: int, seq: int = 7) -> dict:
+    """A wire snapshot of ``n_jobs`` random jobs, each with some open
+    tasks, and one done job."""
+    jobs = []
+    for jid in range(n_jobs):
+        q = int(rng.integers(1, MAX_TASKS + 1))
+        tasks = [(100 * jid + s, int(rng.integers(N_HOSTS)), s)
+                 for s in range(q) if rng.random() < 0.7]
+        jobs.append(wire.job_to_wire(jid, q, rand_mt(rng, q),
+                                     open_count=len(tasks),
+                                     deadline=bool(jid % 2), tasks=tasks))
+    return wire.snapshot_to_wire("t", seq, rand_mh(rng), jobs=jobs,
+                                 done=[{"id": 999, "times": [1.0, 2.5]}])
+
+
+def _with(n_jobs: int, edit=None):
+    """A case: a wire snapshot of ``n_jobs`` jobs, then ``edit(snap)``."""
+    def make(rng):
+        snap = wire_snap(rng, n_jobs)
+        if edit is not None:
+            edit(snap)
+        return snap
+    return make
+
+
+def _nonfinite(*where):
+    """NaN, inf and values beyond ``FEATURE_CLIP`` in M_H and/or in the
+    M_T of jobs 2 and 5."""
+    def edit(snap):
+        if "m_h" in where:
+            mh = snap["m_h"]
+            mh[0], mh[5], mh[7] = np.nan, np.inf, -3e6
+        if "m_t" in where:
+            mt2, mt5 = snap["jobs"][2]["m_t"], snap["jobs"][5]["m_t"]
+            mt2[1], mt2[3], mt2[4] = np.nan, -np.inf, 2e7
+            mt5[0], mt5[6] = np.inf, -0.0
+            snap["jobs"][0]["m_t"][2] = -0.0
+    return edit
+
+
+def _nan_then_bad_q(snap):
+    snap["jobs"][1]["m_t"][0] = np.nan
+    snap["jobs"][3]["q"] = MAX_TASKS + 2
+
+
+def _nested(key):
+    def edit(snap):
+        if key == "m_h":
+            snap["m_h"] = np.reshape(snap["m_h"], (N_HOSTS, -1)).tolist()
+        else:
+            snap["jobs"][1]["m_t"] = np.reshape(
+                snap["jobs"][1]["m_t"], (MAX_TASKS, -1)).tolist()
+    return edit
+
+
+def _ndarray(key):
+    def edit(snap):
+        if key == "m_h":
+            snap["m_h"] = np.float32(snap["m_h"]).reshape(N_HOSTS, -1)
+        else:
+            snap["jobs"][2]["m_t"] = np.float32(snap["jobs"][2]["m_t"])
+    return edit
+
+
+def _set_job(i, key, value):
+    def edit(snap):
+        snap["jobs"][i][key] = value
+    return edit
+
+
+def _set_feature(value, job=None, i=2):
+    """Element ``i`` of M_H, or of job ``job``'s M_T, set to ``value``."""
+    def edit(snap):
+        (snap["m_h"] if job is None else snap["jobs"][job]["m_t"])[i] = value
+    return edit
+
+
+#: case -> (snapshot from a generator, whether it is the wire's plain
+#: form, the bulk path's input)
+BULK_CASES = {
+    "jobs0": (_with(0), True),
+    "jobs1": (_with(1), True),
+    "jobs20": (_with(20), True),
+    "jobs120": (_with(120), True),
+    "nonfinite_in_m_h": (_with(8, _nonfinite("m_h")), True),
+    "nonfinite_in_m_t": (_with(8, _nonfinite("m_t")), True),
+    "nonfinite_in_both": (_with(8, _nonfinite("m_h", "m_t")), True),
+    "nan_in_job1_bad_q_in_job3": (_with(6, _nan_then_bad_q), True),
+    "slot_out_of_range": (
+        _with(4, _set_job(2, "tasks", [[7, 0, 1], [8, 1, MAX_TASKS]])),
+        True),
+    "negative_slot": (_with(4, _set_job(1, "tasks", [[7, 0, -1]])), True),
+    "mt_wrong_length": (
+        _with(4, _set_job(1, "m_t", [0.5] * (MAX_TASKS * 5 - 1))), False),
+    "non_integer_open": (_with(4, _set_job(2, "open", 1.5)), True),
+    "task_entry_of_2": (_with(4, _set_job(1, "tasks", [[7, 0]])), False),
+    "task_entry_of_4": (_with(4, _set_job(1, "tasks", [[7, 0, 2, 9]])),
+                        False),
+    "float_task_field": (_with(4, _set_job(0, "tasks", [[7.0, 0, 1]])),
+                         False),
+    "none_in_m_h": (_with(3, _set_feature(None)), True),
+    "list_in_m_t": (_with(3, _set_feature([0.5], job=1)), False),
+    "nested_m_h": (_with(5, _nested("m_h")), False),
+    "nested_m_t": (_with(5, _nested("m_t")), False),
+    "ndarray_m_h": (_with(5, _ndarray("m_h")), False),
+    "ndarray_m_t": (_with(5, _ndarray("m_t")), False),
+}
+
+
+def _outcome(snap, mode, **kw):
+    try:
+        return sanitize_snapshot(snap, profile(), -1.0, mode=mode, **kw)
+    except Exception as e:      # refusals of any kind are compared
+        return e
+
+
+def _same_array(a, b):
+    assert a.dtype == b.dtype and a.shape == b.shape
+    assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("mode", ["clamp", "reject"])
+@pytest.mark.parametrize("case", sorted(BULK_CASES))
+def test_sanitizer_bulk_path_matches_per_job_path(case, mode):
+    """A wire snapshot converted in bulk gives exactly what the per-job
+    path gives it: the same arrays, bitwise and in dtype, and the same
+    issues in order, or the same refusal; other inputs take the per-job
+    path."""
+    make, plain = BULK_CASES[case]
+    snap = make(np.random.default_rng(sorted(BULK_CASES).index(case)))
+    counters = {"sanitize_bulk": 0}
+    got = _outcome(snap, mode, counters=counters)
+    with mock.patch.object(svc_sanitize, "_wire_floats",
+                           return_value=None), \
+            mock.patch.object(svc_sanitize, "_jobs_bulk",
+                              return_value=None):
+        want = _outcome(snap, mode)
+    if isinstance(want, Exception):
+        assert type(got) is type(want) and str(got) == str(want)
+        assert getattr(got, "code", None) == getattr(want, "code", None)
+    else:
+        assert not isinstance(got, Exception), got
+        assert got.keys() == want.keys()
+        assert (got["seq"], got["issues"]) == (want["seq"], want["issues"])
+        _same_array(got["m_h"], want["m_h"])
+        assert len(got["jobs"]) == len(want["jobs"])
+        for a, b in zip(got["jobs"], want["jobs"]):
+            assert a.keys() == b.keys()
+            assert [a[k] for k in ("id", "q", "open", "deadline")] \
+                == [b[k] for k in ("id", "q", "open", "deadline")]
+            _same_array(a["m_t"], b["m_t"])
+            for x, y in zip(a["tasks"], b["tasks"], strict=True):
+                _same_array(x, y)
+        assert len(got["done"]) == len(want["done"])
+        for a, b in zip(got["done"], want["done"]):
+            assert a["id"] == b["id"]
+            _same_array(a["times"], b["times"])
+    assert counters["sanitize_bulk"] == int(
+        plain and not isinstance(got, Exception))
+
+
+def test_sanitizer_bulk_numpy_calls_do_not_grow_with_jobs():
+    """The bulk path's numpy conversions are per snapshot, not per job."""
+    rng = np.random.default_rng(3)
+    calls = []
+    for n_jobs in (1, 120):
+        snap = wire_snap(rng, n_jobs)
+        counters = {"sanitize_bulk": 0}
+        with mock.patch.object(np, "fromiter", wraps=np.fromiter) as fi, \
+                mock.patch.object(np, "asarray", wraps=np.asarray) as aa:
+            sanitize_snapshot(snap, profile(), -1.0, counters=counters)
+        assert counters["sanitize_bulk"] == 1
+        calls.append((fi.call_count, aa.call_count))
+    assert calls[0] == calls[1], calls
+
+
+def test_service_counts_snapshots_sanitized_in_bulk():
+    rng = np.random.default_rng(4)
+    svc = PredictionService(ServiceConfig(profile=profile()))
+    for t in ("a", "b"):
+        assert svc.hello(t, profile().to_wire())["ok"]
+    for seq in range(2):
+        for t in ("a", "b"):
+            svc.submit(t, wire_snap(rng, 3, seq))
+    in_process = wire_snap(rng, 3, 2)
+    in_process["m_h"] = rand_mh(rng)            # an ndarray: per-job path
+    assert svc.submit("a", in_process).result is None
+    refused = wire_snap(rng, 3, 2)
+    refused["jobs"][1]["q"] = 0
+    assert svc.submit("b", refused).result["error"] == "bad-job"
+    st = svc.stats()
+    assert st["sanitize_bulk"] == 4 and st["rejected"] == 1
 
 
 # --------------------------- admission / queues --------------------------
