@@ -2,8 +2,8 @@
 
 PY := python
 
-.PHONY: test test-all lint sweep-bench engine-bench kernel-bench bench \
-	regen-golden nightly-grid serve serve-bench chaos chaos-drill
+.PHONY: test test-all lint bench regen-golden nightly-grid serve chaos \
+	chaos-drill
 
 test:  ## fast lane: what CI runs (slow-marked distributed tests excluded)
 	PYTHONPATH=src $(PY) -m pytest -q -m "not slow"
@@ -20,20 +20,8 @@ chaos:  ## full chaos suite: fault-injection drills + codec property tests
 chaos-drill:  ## seeded acceptance drills outside pytest -> artifacts/chaos/
 	PYTHONPATH=src $(PY) benchmarks/chaos_drill.py --seeds $${REPRO_CHAOS_SEEDS:-0}
 
-sweep-bench:  ## serial vs cold/warm-pool sweep benchmark -> BENCH_sweep.json
-	PYTHONPATH=src $(PY) benchmarks/sweep_bench.py
-
-engine-bench:  ## single-cell (planetlab x start) benchmark -> BENCH_engine.json
-	PYTHONPATH=src $(PY) benchmarks/engine_bench.py
-
-kernel-bench:  ## fused Pallas LSTM cell fwd+VJP benchmark -> BENCH_kernel.json
-	PYTHONPATH=src $(PY) benchmarks/kernel_bench.py
-
 serve:  ## prediction-service demo: daemon + TCP tenants + retrain cycle
 	PYTHONPATH=src $(PY) examples/predict_service.py
-
-serve-bench:  ## concurrent multi-tenant serving benchmark -> BENCH_serve.json
-	PYTHONPATH=src $(PY) benchmarks/serve_bench.py
 
 bench:  ## paper figure reproductions (scaled-down)
 	PYTHONPATH=src $(PY) -m benchmarks.run

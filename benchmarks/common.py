@@ -1,21 +1,11 @@
-"""Shared benchmark utilities: CSV/artifact emission, technique runners."""
+"""Shared benchmark utilities: CSV artifact emission."""
 from __future__ import annotations
 
 import csv
 import os
-import platform
-import time
 
 ARTIFACTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "artifacts")
-
-
-def host_fingerprint() -> str:
-    """Coarse hardware identity for the perf artifacts: wall-clock
-    numbers are only comparable between benches run on matching
-    fingerprints (``check_perf.py`` skips the regression compare on
-    mismatch)."""
-    return f"{platform.machine()}-{os.cpu_count()}cpu-{platform.system()}"
 
 
 def write_csv(name: str, header: list[str], rows: list[list]) -> str:
@@ -27,13 +17,3 @@ def write_csv(name: str, header: list[str], rows: list[list]) -> str:
         w.writerows(rows)
     return path
 
-
-def bench_row(name: str, fn, *args, derived="", repeats: int = 1,
-              **kw) -> list:
-    """name,us_per_call,derived CSV row (scaffold contract)."""
-    t0 = time.perf_counter()
-    out = None
-    for _ in range(repeats):
-        out = fn(*args, **kw)
-    us = (time.perf_counter() - t0) / repeats * 1e6
-    return [name, round(us, 1), derived if derived else out]

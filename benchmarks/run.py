@@ -1,8 +1,10 @@
 """Benchmark entry point: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows (scaffold contract), followed
-by the figure headline summaries and — when dry-run artifacts exist — the
-roofline table. ``--full`` switches the simulator to Table-4 scale.
+Prints ``name,us_per_call,derived`` CSV rows, one per figure with its
+headline summary, and — when dry-run artifacts exist — the roofline
+table. ``--full`` switches the simulator to Table-4 scale.  The speed of
+the decision path is measured on the chip by ``bench/run.py`` (cells in
+``BENCHMARK.json``), not here.
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--skip-sim]
 """
@@ -21,10 +23,6 @@ def main() -> None:
     args, _ = ap.parse_known_args()
 
     print("name,us_per_call,derived")
-
-    from benchmarks.kernel_bench import rows as kernel_rows
-    for r in kernel_rows():
-        print(",".join(str(x) for x in r))
 
     if not args.skip_sim:
         from benchmarks import sim_experiments as S

@@ -182,10 +182,8 @@ def phase_simulate(sz: Sizing, cfg, tech):
     from repro.sim.engine import Simulation
     from repro.sim.techniques.start_tech import STARTEager
 
-    ctrl = tech._controller
-    if not ctrl.use_fused_step:
-        raise AssertionError("the fused decision step is off")
-    pred = ctrl.predictor
+    pred = tech._controller.predictor
+    calls0 = pred.fused_calls
     rec = _Recorder(pred)
     run_cfg = dataclasses.replace(cfg, n_intervals=sz.n_intervals)
     t0 = time.perf_counter()
@@ -199,10 +197,12 @@ def phase_simulate(sz: Sizing, cfg, tech):
         f"sla_violation_rate {summary['sla_violation_rate']:.4f}; "
         f"{_CLOCK}: {wall:.2f} s wall")
     _timing("start fused step", rec)
-    if fused_compile_count() <= 0 or pred.h2d_stages <= 0 or not rec.calls:
+    if (fused_compile_count() <= 0 or pred.h2d_stages <= 0 or not rec.calls
+            or pred.fused_calls - calls0 != len(rec.calls)):
         raise AssertionError(
             f"fused step not taken: compiles={fused_compile_count()} "
-            f"h2d_stages={pred.h2d_stages} calls={len(rec.calls)}")
+            f"h2d_stages={pred.h2d_stages} calls={len(rec.calls)} "
+            f"fused_calls={pred.fused_calls - calls0}")
     if not all(np.isfinite(e).all() for e in es):
         raise AssertionError("non-finite E_S from the fused step")
     drift = _tier1_check(pred, rec.calls, "start")

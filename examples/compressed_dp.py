@@ -10,8 +10,6 @@ os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
 import jax                                                   # noqa: E402
-import jax.numpy as jnp                                      # noqa: E402
-import numpy as np                                           # noqa: E402
 from jax.sharding import PartitionSpec as P                  # noqa: E402
 
 from jax.experimental.shard_map import shard_map             # noqa: E402

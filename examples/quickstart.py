@@ -8,7 +8,6 @@
     PYTHONPATH=src python examples/quickstart.py
 """
 import jax
-import numpy as np
 
 from repro.core import pareto
 from repro.sim import Simulation, small

@@ -32,8 +32,8 @@ Determinism is **tiered** (see README "Performance"):
   * Tier-1 (tolerance-bounded): the fused step and the serving batch
     path.  They restructure the emission for speed — encoder hoisted
     out of the scan with the shared host block encoded once per step
-    (``net.encoder_hoisted``), the scan unrolled (``unroll``), the
-    Pareto tail fused into the same program, exact-shape batches — and
+    (``net.encoder_hoisted``), the scan unrolled by 2, the Pareto tail
+    fused into the same program, exact-shape batches — and
     agree with the reference within the documented bound in
     ``tests/tolerance.py`` at every shape (tested by shape sweep).
     Every Tier-1 path is still fully deterministic run-to-run on one
@@ -70,6 +70,16 @@ def bucket_size(n: int) -> int:
 # slot rebuilds) ride the pjit fast path (see StragglerPredictor._stage)
 _stage_put = jax.jit(lambda x: x)
 
+#: the fused programs' batch policy (``StragglerPredictor.batch_size``):
+#: a count whose power-of-two bucket would waste more than this share of
+#: its rows runs at its exact width instead (0.44 of a 16-bucket for a
+#: 9-job batch) ...
+PAD_WASTE_LIMIT = 0.25
+#: ... for at most this many distinct exact shapes per predictor; once
+#: spent, new counts pad again, so a long-lived process compiles at most
+#: ``len(buckets) + EXACT_SHAPES_LIMIT`` batch shapes.
+EXACT_SHAPES_LIMIT = 8
+
 
 # --------------------------- fused interval step ---------------------------
 #
@@ -95,8 +105,7 @@ def _fused_step(params, ring, packed, *, nb: int, task_dim: int,
         job) (``net.encoder_hoisted`` — the task block's constant-EMA
         is dropped there too);
       * the LSTM scan unrolls by the static ``unroll`` factor
-        (autotuned per bucket via
-        :meth:`StragglerPredictor.autotune_unroll`);
+        (:meth:`StragglerPredictor._unroll`);
       * the Pareto tail — and with ``per_task=True`` the per-task score
         decomposition — is fused INTO this program, so a warm interval
         is exactly one dispatch and one readback (historically the tail
@@ -313,29 +322,12 @@ class StragglerPredictor:
     # tested against the jnp cell).  Applies to inference AND training
     # (fit routes train_step through the same cell).
     use_pallas_cell: bool | str = False
-    # ----- Tier-1 knobs (fused step + serving batch path only) -----
-    #: ``lax.scan`` unroll factor for the emission loop.  ``None`` = auto
-    #: (full unroll while the horizon is small — deterministic, no
-    #: timing involved); per-bucket autotuned overrides land in
-    #: ``_unroll_for_bucket`` via :meth:`autotune_unroll`.
-    unroll: int | None = None
-    #: skip power-of-two padding when the padded bucket would waste more
-    #: than this fraction of its rows (0.44 of a 16-bucket for a 9-job
-    #: batch); 1.0 disables exact shapes entirely.
-    exact_shape_waste: float = 0.25
-    #: at most this many distinct exact shapes ever compile — once spent,
-    #: new job counts fall back to their power-of-two bucket, so the
-    #: steady-state compile count stays bounded by
-    #: ``len(buckets) + exact_shape_budget`` however long the process
-    #: serves (the retrace guarantee the padding existed for).
-    exact_shape_budget: int = 8
 
     def __post_init__(self):
         self.input_dim = features.input_dim(self.n_hosts, self.max_tasks)
         self.host_dim = self.n_hosts * features.HOST_FEATURES
         self.task_dim = self.max_tasks * features.TASK_FEATURES
         self._exact_shapes: set[int] = set()
-        self._unroll_for_bucket: dict[int, int] = {}
         # params live on device for their whole lifetime — predictions
         # upload only the per-interval feature batch
         self.params = jax.device_put(
@@ -429,8 +421,8 @@ class StragglerPredictor:
         """The batch axis the jitted programs see for ``n`` real jobs.
 
         Power-of-two bucketing keeps the compile count bounded; when the
-        bucket would waste more than ``exact_shape_waste`` of its rows
-        the exact count is used instead — up to ``exact_shape_budget``
+        bucket would waste more than ``PAD_WASTE_LIMIT`` of its rows the
+        exact count is used instead — up to ``EXACT_SHAPES_LIMIT``
         distinct exact shapes, after which new counts pad again (a
         long-lived process must not compile without bound).  Decisions
         are a pure function of the call sequence, so replaying a
@@ -438,68 +430,21 @@ class StragglerPredictor:
         warm-cell zero-retrace accounting survive."""
         n = int(n)
         nb = bucket_size(n)
-        if n and nb > n and (nb - n) / nb > self.exact_shape_waste:
+        if n and nb > n and (nb - n) / nb > PAD_WASTE_LIMIT:
             if n in self._exact_shapes \
-                    or len(self._exact_shapes) < self.exact_shape_budget:
+                    or len(self._exact_shapes) < EXACT_SHAPES_LIMIT:
                 self._exact_shapes.add(n)
                 return n
         return nb
 
     def _unroll(self, nb: int) -> int:
-        """Scan-unroll factor for a batch bucket: the autotuned choice
-        when :meth:`autotune_unroll` recorded one, else the ``unroll``
-        knob, else 2 — measured fastest across the small-batch range on
-        CPU (unroll=1 pays scan while-loop machinery per step; full
-        unroll at T=5 inflates the program enough that dispatch gets
-        slower, not faster).  The default is a fixed constant, never
+        """Scan-unroll factor of the fused programs at batch ``nb``: 2,
+        or the horizon if shorter — measured fastest across the
+        small-batch range (unroll=1 pays scan while-loop machinery per
+        step; full unroll at T=5 inflates the program enough that
+        dispatch gets slower, not faster).  A constant, never
         timing-derived, so every process runs identical programs."""
-        u = self._unroll_for_bucket.get(nb)
-        if u:
-            return u
-        if self.unroll:
-            return int(self.unroll)
         return min(2, self.horizon)
-
-    def autotune_unroll(self, buckets=None, candidates=(1, 2, 0),
-                        repeats: int = 10) -> dict[int, int]:
-        """Time the fused step per bucket across unroll candidates and pin
-        the fastest (0 in ``candidates`` means "full horizon").
-
-        Meant for warmup (benchmarks, the serving daemon's bring-up):
-        each (bucket, unroll) pair compiles once here, so steady state
-        pays nothing new.  The choice is stored per bucket in
-        ``_unroll_for_bucket`` — plain host state that survives
-        pickling, so a pretrained technique broadcast to sweep workers
-        carries its tuning and every process runs identical programs
-        (numerics depend on the unroll factor, Tier-1)."""
-        import time as _time
-        buckets = sorted(buckets or self.buckets_used or
-                         {1, 4, 16})
-        cands = [self.horizon if c == 0 else int(c) for c in candidates]
-        rng = np.random.default_rng(0)
-        for nb in buckets:
-            size = _N_SCALARS + self.host_dim + nb * (1 + self.task_dim)
-            packed = rng.uniform(0.1, 1.0, size).astype(np.float32)
-            packed[0], packed[1] = self.k, self.beta_scale
-            best, best_t = None, None
-            for u in dict.fromkeys(cands):
-                ring = jax.device_put(np.zeros(
-                    (self.horizon, self.host_dim), np.float32))
-                out = None
-                ts = []
-                for _ in range(repeats + 1):
-                    t0 = _time.perf_counter()
-                    ring, out = _fused_step(
-                        self.params, ring, packed, nb=nb,
-                        task_dim=self.task_dim,
-                        use_pallas=self.use_pallas_cell, unroll=u)
-                    jax.block_until_ready(out)
-                    ts.append(_time.perf_counter() - t0)
-                med = float(np.median(ts[1:]))  # drop the compile call
-                if best_t is None or med < best_t:
-                    best, best_t = u, med
-            self._unroll_for_bucket[nb] = best
-        return dict(self._unroll_for_bucket)
 
     @property
     def fused_ready(self) -> bool:

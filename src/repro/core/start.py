@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import os
 from typing import Sequence
 
 import numpy as np
@@ -37,19 +36,19 @@ class JobView:
 class STARTController:
     """Algorithm-1 controller.
 
-    ``use_fused_step`` (default on) routes the per-interval prediction
-    through the predictor's fused device program: the M_H history lives in
-    a donated device ring buffer and one jitted call per interval replaces
-    feature re-upload plus ~10 eager dispatches.  Results are bitwise
-    identical to the unfused path (tested; the determinism golden fixture
-    pins it).  Set ``REPRO_DISABLE_FUSED_STEP=1`` to force the historical
-    path for debugging.
+    Each observed host matrix is pushed to the predictor, and the first
+    prediction of an interval runs the predictor's fused device program
+    (``predict_interval``): the M_H history lives in a donated device ring
+    buffer and one jitted call replaces feature re-upload plus ~10 eager
+    dispatches.  A repeat prediction in the same interval (no fresh host
+    row) takes the unfused ``predict_features`` path, the Tier-0 reference
+    the fused program agrees with within the Tier-1 bound.
     """
 
     def __init__(self, n_hosts: int, max_tasks: int, k: float = 1.5,
                  horizon: int = 5, seed: int = 0,
                  ma_decay: float = 0.8, beta_scale: float = 1.0,
-                 use_fused_step: bool = True, trigger: str = "milestone",
+                 trigger: str = "milestone",
                  score_on: float = 0.0, hysteresis: int = 2,
                  cooldown: int = 5,
                  predictor: StragglerPredictor | None = None):
@@ -66,8 +65,6 @@ class STARTController:
                 seed=seed, beta_scale=beta_scale)
         self.ma = mitigation.StragglerMovingAverage(n_hosts, decay=ma_decay)
         self.horizon = horizon
-        self.use_fused_step = use_fused_step and not os.environ.get(
-            "REPRO_DISABLE_FUSED_STEP")
         #: "milestone" — Algorithm 1 verbatim: act once a job is down to
         #: floor(E_S) open tasks.  "per_task" — act as soon as the
         #: predicted straggler set is nonempty: each interval the
@@ -93,8 +90,7 @@ class STARTController:
     def observe_hosts(self, m_h: np.ndarray) -> None:
         m_h = np.asarray(m_h, np.float32)
         self._host_hist.append(m_h)
-        if self.use_fused_step:
-            self.predictor.push_host_row(m_h)
+        self.predictor.push_host_row(m_h)
 
     def observe_straggler_counts(self, counts: np.ndarray) -> None:
         self.ma.update(counts)
@@ -109,11 +105,21 @@ class STARTController:
         straggler forecast, logged for the Fig. 9 MAPE comparison."""
         return float(sum(self._es_cache.get(j, 0.0) for j in job_ids))
 
-    def _host_seq(self) -> np.ndarray:
+    @property
+    def host_rows(self) -> tuple[np.ndarray, ...]:
+        """The last ``horizon`` observed host matrices, oldest first."""
+        return tuple(self._host_hist)
+
+    def host_seq(self) -> np.ndarray:
+        """The (horizon, n_hosts, HOST_FEATURES) host window the network
+        reads, left-padded with the oldest snapshot while fewer than
+        ``horizon`` have been observed."""
         hist = list(self._host_hist)
         while len(hist) < self.horizon:  # left-pad with oldest snapshot
             hist.insert(0, hist[0])
         return np.stack(hist[-self.horizon:])
+
+    _host_seq = host_seq     # read by bench/servicecell.py
 
     # ------------------------------ decision ------------------------------
 
@@ -141,6 +147,17 @@ class STARTController:
         e_s = np.where(np.isfinite(e_s), e_s, 0.0)
         return np.clip(e_s, 0.0, np.asarray(q, e_s.dtype))
 
+    def record_es(self, job_ids, e_s: np.ndarray,
+                  q: np.ndarray) -> np.ndarray:
+        """Sanitize a batch of E_S (:meth:`_sanitize_es`) and record it
+        per job for :meth:`es_total`; returns the sanitized batch.  Every
+        prediction route ends here, the service's tenant batch and
+        degraded mode included."""
+        e_s = self._sanitize_es(e_s, q)
+        for j, e in zip(job_ids, e_s):
+            self._es_cache[int(j)] = float(e)
+        return e_s
+
     def predict_es_batch(self, job_ids: np.ndarray, m_t: np.ndarray,
                          q: np.ndarray) -> np.ndarray:
         """Array-native PredictStraggler over the active-job batch (the
@@ -148,23 +165,20 @@ class STARTController:
 
         Feature assembly is pure numpy; the predictor pads the job batch
         to a power-of-two bucket so the jitted network compiles once per
-        bucket, never once per job count.  With the fused step enabled
-        the whole pipeline (ring roll, assembly, network, Pareto tail)
-        runs device-resident per interval; a repeat predict within the
-        same interval (no fresh host row) falls back to the
-        bitwise-identical unfused path."""
+        bucket, never once per job count.  The first predict of an
+        interval runs the whole pipeline (ring roll, assembly, network,
+        Pareto tail) device-resident as the fused step; a repeat predict
+        within the same interval (no fresh host row) falls back to the
+        unfused Tier-0 path."""
         if len(job_ids) == 0 or not self._host_hist:
             return np.zeros(len(job_ids))
         q = np.asarray(q, np.float32)
-        if self.use_fused_step and self.predictor.fused_ready:
+        if self.predictor.fused_ready:
             e_s = self.predictor.predict_interval(m_t, q)
         else:
-            pred = self.predictor.predict_features(self._host_seq(), m_t, q)
+            pred = self.predictor.predict_features(self.host_seq(), m_t, q)
             e_s = np.asarray(pred.e_s)
-        e_s = self._sanitize_es(e_s, q)
-        for j, e in zip(job_ids, e_s):
-            self._es_cache[int(j)] = float(e)
-        return e_s
+        return self.record_es(job_ids, e_s, q)
 
     def predict_scores_batch(self, job_ids: np.ndarray, m_t: np.ndarray,
                              q: np.ndarray
@@ -178,16 +192,14 @@ class STARTController:
             return (np.zeros(len(job_ids)),
                     np.zeros((len(job_ids), self.predictor.max_tasks)))
         q = np.asarray(q, np.float32)
-        if self.use_fused_step and self.predictor.fused_ready:
+        if self.predictor.fused_ready:
             e_s, scores = self.predictor.predict_interval(
                 m_t, q, per_task=True)
         else:
             e_s, scores = self.predictor.predict_features(
-                self._host_seq(), m_t, q, per_task=True)
-        e_s = self._sanitize_es(e_s, q)
+                self.host_seq(), m_t, q, per_task=True)
+        e_s = self.record_es(job_ids, e_s, q)
         scores = np.where(np.isfinite(scores), scores, 0.0)
-        for j, e in zip(job_ids, e_s):
-            self._es_cache[int(j)] = float(e)
         return e_s, scores
 
     def decide_arrays(self, job_ids: np.ndarray, m_t: np.ndarray,

@@ -434,7 +434,7 @@ class PredictionService:
             m_t = t.mt_cache.pop(d["id"], None)
             t.controller.job_finished(d["id"])
             if m_t is not None and not self.degraded:
-                host_seq = t.controller._host_seq().reshape(
+                host_seq = t.controller.host_seq().reshape(
                     self.profile.horizon, -1)
                 self.buffer.add_job(host_seq, m_t, times,
                                     self.profile.beta_scale)
@@ -527,7 +527,7 @@ class PredictionService:
                        else np.zeros(mt_shape, np.float32))
                 q = np.array([j["q"] for j in jobs], np.float32)
                 group.append((t.slot, t.snapshots,
-                              t.controller._host_hist, m_t, q))
+                              t.controller.host_rows, m_t, q))
             buf, nb, n = self.model.pack_tenants(group)
         out = self.model.predict_tenants(buf, nb, n, len(group),
                                          per_task=per_task)
@@ -542,9 +542,8 @@ class PredictionService:
                                   out[lo:hi, 1:], 0.0)
             else:
                 e_s, scores = out[lo:hi], None
-            e_s = STARTController._sanitize_es(e_s, q)
-            for j, e in zip(p.snap["jobs"], e_s):
-                t.controller._es_cache[j["id"]] = float(e)
+            e_s = t.controller.record_es(
+                [j["id"] for j in p.snap["jobs"]], e_s, q)
             preds[p.tenant] = (e_s, scores)
             lo = hi
 
@@ -572,14 +571,12 @@ class PredictionService:
             e_s = np.asarray(e_s)[:n]
         else:
             e_s = np.zeros(n)
-        e_s = STARTController._sanitize_es(e_s, q)
+        e_s = t.controller.record_es(ids, e_s, q)
         scores = None
         if per_task:
             scores = np.zeros((n, self.profile.max_tasks), np.float32)
             for i in range(n):
                 scores[i, :int(q[i])] = e_s[i] / max(q[i], 1.0)
-        for j, e in zip(ids, e_s):
-            t.controller._es_cache[int(j)] = float(e)
         return e_s, scores
 
     # ------------------------------ dispatch ----------------------------

@@ -1,8 +1,8 @@
 """Remote elastic sweep fabric: cross-machine unit scheduling.
 
 The process-pool sweep (``repro.sim.sweep``) tops out at one host's
-cores — the committed ``BENCH_sweep.json`` shows parallelism *losing*
-on a 1-cpu container.  This module serves the exact same
+cores, and on a one-core host parallelism loses to the serial run.
+This module serves the exact same
 ``(spec, unit, payload)`` tuples the pool consumes to **node agents on
 other machines**:
 

@@ -107,11 +107,9 @@ class START(Policy):
                  seed: int = 0, margin: float | None = None,
                  margin_lo: float = -0.50, margin_hi: float = 0.60,
                  rerun_margin_floor: float = 0.10,
-                 k_lo: float = 1.0, k_hi: float = 1.5,
-                 use_fused_step: bool = True):
+                 k_lo: float = 1.0, k_hi: float = 1.5):
         self._controller = controller
         self.controller = controller
-        self.use_fused_step = use_fused_step   # forwards to the controller
         self.seed = seed
         self.margin = margin
         self.margin_lo = margin_lo
@@ -121,23 +119,6 @@ class START(Policy):
         self.k_hi = k_hi
         self._util = 0.0
         self._last_es_sum: float | None = None
-
-    @property
-    def use_fused_step(self) -> bool:
-        """Whether the per-interval pipeline runs as the fused device
-        program.  Forwards to the bound controller so the policy flag
-        can never disagree with actual behavior — setting it at any
-        point (constructor kwarg, sweep ``technique_kwargs``, or plain
-        attribute assignment on a pretrained instance) takes effect."""
-        if self._controller is not None:
-            return self._controller.use_fused_step
-        return self._use_fused_step
-
-    @use_fused_step.setter
-    def use_fused_step(self, value: bool) -> None:
-        self._use_fused_step = bool(value)
-        if self._controller is not None:
-            self._controller.use_fused_step = bool(value)
 
     # ------------------------------ pretraining ----------------------------
 
@@ -156,8 +137,7 @@ class START(Policy):
             cfg = view.config
             self._controller = STARTController(
                 n_hosts=cfg.n_hosts, max_tasks=cfg.max_tasks,
-                k=cfg.k, seed=self.seed,
-                use_fused_step=self.use_fused_step)
+                k=cfg.k, seed=self.seed)
         self.controller = self._controller
         return self._controller
 

@@ -152,39 +152,63 @@ def test_sanitize_es_clamps_and_zeroes_nonfinite():
     np.testing.assert_array_equal(got, [0.0, 0.0, 0.0, 0.0, 2.5, 4.0])
 
 
+def _script_predictor(pred, e_s, n_tasks):
+    """Script both prediction routes of ``pred`` to answer ``e_s`` (an
+    (n,) array) with scores of ``e_s`` on every slot: the fused
+    ``predict_interval``, which the controller calls once a fresh host
+    row is staged, and the unfused ``predict_features`` it falls back to
+    for a repeat predict in the same interval.  Returns the routes each
+    call took."""
+    taken = []
+    scores = np.repeat(e_s[:, None], n_tasks, axis=1)
+
+    def fused(self, m_t, q, per_task=False):
+        taken.append("fused")
+        self._ring_rows = self._host_rows   # the staged row is consumed
+        return (e_s, scores) if per_task else e_s
+
+    def unfused(self, m_h_seq, m_t, q, per_task=False):
+        taken.append("unfused")
+        return (e_s, scores) if per_task else types.SimpleNamespace(e_s=e_s)
+
+    pred.predict_interval = types.MethodType(fused, pred)
+    pred.predict_features = types.MethodType(unfused, pred)
+    return taken
+
+
 @pytest.mark.parametrize("bad", [np.nan, np.inf])
 def test_nonfinite_es_cannot_fire_or_crash_either_trigger(bad):
     """A NaN/inf network output used to flow into np.floor and either
     crash ``decide`` or permanently force-fire ``decide_arrays``; it
-    must now read as 'no predicted stragglers' on both paths."""
+    must now read as 'no predicted stragglers' on both triggers and on
+    both routes: the fused step, and the repeat predict in the same
+    interval that falls back to ``predict_features``."""
     for trigger in ("milestone", "per_task"):
         ctrl = STARTController(n_hosts=4, max_tasks=3, trigger=trigger,
-                               hysteresis=1, use_fused_step=False)
+                               hysteresis=1)
         ctrl.observe_hosts(np.zeros((4, features.HOST_FEATURES),
                                     np.float32))
-        ctrl.predictor.predict_features = types.MethodType(
-            lambda self, *a, **kw:
-            (np.full(2, bad), np.full((2, 3), bad)) if kw.get("per_task")
-            else types.SimpleNamespace(e_s=np.full(2, bad)),
-            ctrl.predictor)
+        taken = _script_predictor(ctrl.predictor, np.full(2, bad), 3)
         m_t = np.zeros((2, 3, features.TASK_FEATURES), np.float32)
-        acts = ctrl.decide_arrays(
-            np.array([0, 1]), m_t, np.array([3.0, 3.0]),
-            np.array([1, 1]), np.array([True, False]),
-            lambda job: ([0], [0], [0]))
-        assert acts == []
-        assert ctrl.es_total([0, 1]) == 0.0
+        for _ in range(2):
+            acts = ctrl.decide_arrays(
+                np.array([0, 1]), m_t, np.array([3.0, 3.0]),
+                np.array([1, 1]), np.array([True, False]),
+                lambda job: ([0], [0], [0]))
+            assert acts == []
+            assert ctrl.es_total([0, 1]) == 0.0
+        assert taken == ["fused", "unfused"]
     # JobView path: int(np.floor(nan)) used to raise ValueError
-    ctrl = STARTController(n_hosts=4, max_tasks=3, use_fused_step=False)
+    ctrl = STARTController(n_hosts=4, max_tasks=3)
     ctrl.observe_hosts(np.zeros((4, features.HOST_FEATURES), np.float32))
-    ctrl.predictor.predict_features = types.MethodType(
-        lambda self, *a, **kw: types.SimpleNamespace(e_s=np.full(1, bad)),
-        ctrl.predictor)
+    taken = _script_predictor(ctrl.predictor, np.full(1, bad), 3)
     jv = JobView(job_id=0, q=3, deadline_oriented=True,
                  incomplete_task_ids=[0], task_hosts=[0],
                  task_matrix=np.zeros((3, features.TASK_FEATURES),
                                       np.float32))
     assert ctrl.decide([jv]) == []
+    assert ctrl.decide([jv]) == []
+    assert taken == ["fused", "unfused"]
 
 
 # ----------------------- per-task trigger unit behavior ---------------------
@@ -193,17 +217,17 @@ def _scripted_controller(es_value, n_tasks=3, **kw):
     """Controller whose prediction is scripted: E_S fixed, scores
     concentrated on slot 0."""
     ctrl = STARTController(n_hosts=4, max_tasks=n_tasks,
-                           trigger="per_task", use_fused_step=False, **kw)
+                           trigger="per_task", **kw)
     ctrl.observe_hosts(np.zeros((4, features.HOST_FEATURES), np.float32))
     scores = np.zeros((1, n_tasks))
     scores[0, 0] = es_value
 
-    def scripted(self, *a, **kwargs):
-        if kwargs.get("per_task"):
+    def scripted(self, m_t, q, per_task=False):
+        if per_task:
             return np.full(1, es_value), scores
-        return types.SimpleNamespace(e_s=np.full(1, es_value))
+        return np.full(1, es_value)
 
-    ctrl.predictor.predict_features = types.MethodType(
+    ctrl.predictor.predict_interval = types.MethodType(
         scripted, ctrl.predictor)
     return ctrl
 
@@ -271,11 +295,11 @@ def test_per_task_load_gate_defers_fire_on_idle_host():
 def test_milestone_trigger_unchanged_by_extended_incomplete_fn():
     """Legacy milestone controllers accept (and ignore) the per-task
     slot element, so one policy-side callback serves both modes."""
-    ctrl = STARTController(n_hosts=4, max_tasks=3, use_fused_step=False)
+    ctrl = STARTController(n_hosts=4, max_tasks=3)
     ctrl.observe_hosts(np.zeros((4, features.HOST_FEATURES), np.float32))
-    ctrl.predictor.predict_features = types.MethodType(
-        lambda self, *a, **kw: types.SimpleNamespace(
-            e_s=np.full(1, 2.0)), ctrl.predictor)
+    ctrl.predictor.predict_interval = types.MethodType(
+        lambda self, m_t, q, per_task=False: np.full(1, 2.0),
+        ctrl.predictor)
     acts = ctrl.decide_arrays(
         np.array([7]), np.zeros((1, 3, features.TASK_FEATURES),
                                 np.float32),

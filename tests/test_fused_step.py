@@ -63,7 +63,6 @@ def test_fused_cell_is_deterministic_across_runs(trained_start_bytes):
     pipeline runs twice from the same pretrained bytes."""
     tech_bytes, cfg = trained_start_bytes
     a = pickle.loads(tech_bytes)
-    assert a._controller.use_fused_step   # the default
     s_a = Simulation(cfg, technique=a).run()
     b = pickle.loads(tech_bytes)
     s_b = Simulation(cfg, technique=b).run()
@@ -73,14 +72,6 @@ def test_fused_cell_is_deterministic_across_runs(trained_start_bytes):
     # interval, nothing else
     pred = a._controller.predictor
     assert pred.h2d_stages > 0
-    # the unfused route still works end to end (service degraded mode,
-    # cold second-predicts) — no equality demanded at cell granularity:
-    # per-interval ulp drift compounds through placement decisions
-    c = pickle.loads(tech_bytes)
-    c.use_fused_step = False      # forwards to the controller
-    assert not c._controller.use_fused_step
-    s_c = Simulation(cfg, technique=c).run()
-    assert deterministic_summary(s_c)["tasks_total"] > 0
 
 
 def test_fused_predict_interval_matches_predict_features():
@@ -121,7 +112,6 @@ def test_fused_predictor_survives_pickling_mid_run():
     rng = np.random.default_rng(1)
     n_hosts, max_tasks = 4, 4
     ctrl = STARTController(n_hosts=n_hosts, max_tasks=max_tasks)
-    assert ctrl.use_fused_step
     for _ in range(3):
         ctrl.observe_hosts(rng.uniform(
             0, 1, (n_hosts, features.HOST_FEATURES)).astype(np.float32))

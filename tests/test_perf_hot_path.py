@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core import encoder_lstm as net
-from repro.core import features
+from repro.core import features, predictor
 from repro.core.predictor import StragglerPredictor, bucket_size
 from repro.sim import Simulation, small, sweep
 from repro.sim.cluster import Cluster
@@ -212,10 +212,10 @@ def test_bucket_size():
         == [1, 1, 2, 4, 4, 8, 8, 16, 32]
 
 
-def test_batch_size_exact_shape_policy_and_budget():
+def test_batch_size_exact_shape_policy_and_budget(monkeypatch):
     """The Tier-1 exact-shape policy: counts whose power-of-two bucket
-    wastes more than ``exact_shape_waste`` run at their exact width, up
-    to ``exact_shape_budget`` distinct shapes; decisions replay
+    wastes more than ``PAD_WASTE_LIMIT`` run at their exact width, up
+    to ``EXACT_SHAPES_LIMIT`` distinct shapes; decisions replay
     deterministically and the budget bounds the steady-state compile
     count of a long-lived process."""
     pred = StragglerPredictor(n_hosts=3, max_tasks=4)
@@ -226,15 +226,15 @@ def test_batch_size_exact_shape_policy_and_budget():
     assert pred.batch_size(8) == 8    # exact power of two: unchanged
     assert pred.batch_size(5) == 5    # replay is deterministic
 
-    tight = StragglerPredictor(n_hosts=3, max_tasks=4,
-                               exact_shape_budget=2)
+    monkeypatch.setattr(predictor, "EXACT_SHAPES_LIMIT", 2)
+    tight = StragglerPredictor(n_hosts=3, max_tasks=4)
     assert tight.batch_size(5) == 5
     assert tight.batch_size(9) == 9
     assert tight.batch_size(17) == 32   # budget spent: new counts pad
     assert tight.batch_size(5) == 5     # seen exact shapes stay exact
 
-    off = StragglerPredictor(n_hosts=3, max_tasks=4,
-                             exact_shape_waste=1.0)
+    monkeypatch.setattr(predictor, "PAD_WASTE_LIMIT", 1.0)
+    off = StragglerPredictor(n_hosts=3, max_tasks=4)
     assert off.batch_size(5) == 8       # policy disabled: pure po2
 
     # the Tier-0 reference path is NOT subject to the policy: its batch

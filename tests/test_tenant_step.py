@@ -165,7 +165,7 @@ def test_slots_follow_host_histories_through_skips_leaves_and_lags():
                 st = svc.tenants[t]
                 assert model._slot_rows[st.slot] == st.snapshots
                 np.testing.assert_array_equal(
-                    slab[st.slot], st.controller._host_seq().reshape(
+                    slab[st.slot], st.controller.host_seq().reshape(
                         HORIZON, -1))
         else:
             assert model.tenant_calls == before[0]

@@ -7,8 +7,8 @@ Three jobs:
   * the shape-sweep regression: every Tier-1 optimization (split-encoder
     hoisting + fused Pareto tail, exact-shape batches, the tenant step's
     segmented encoder at each scan unroll) pinned against the Tier-0
-    reference at every swept shape, with the worst observed ulp drift pinned so growth is
-    visible in review;
+    reference at every swept shape, with the worst observed ulp drift
+    pinned so growth is visible in review;
   * the Tier-0 firewall: the bitwise path (engine, sweep, golden
     fixture) must never import the tolerance helper — Tier-0 has no
     tolerances.
@@ -23,7 +23,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import features
+from repro.core import features, predictor
 from repro.core.predictor import StragglerPredictor
 
 from tolerance import (TIER1_MAX_ULP, TIER1_REL, assert_tier1, drift,
@@ -108,14 +108,14 @@ def test_sweep_drift_aggregates_worst_pair():
 _COUNTS = (1, 2, 3, 5, 8, 9, 12, 16)
 
 
-def _fused_vs_reference(exact_shapes: bool):
+def _fused_vs_reference(monkeypatch, exact_shapes: bool):
     """Warm fused intervals vs predict_features at every swept count;
     ``exact_shapes`` toggles the exact-shape batch policy so its drift
     contribution is isolated from the hoisting + fused-tail rewrite."""
+    if not exact_shapes:
+        monkeypatch.setattr(predictor, "PAD_WASTE_LIMIT", 1.0)
     n_hosts, max_tasks = 6, 5
-    pred = StragglerPredictor(
-        n_hosts=n_hosts, max_tasks=max_tasks,
-        exact_shape_waste=0.25 if exact_shapes else 1.0)
+    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks)
     rng = np.random.default_rng(7)
     t = pred.horizon
     rows = [rng.uniform(0, 1, (n_hosts, features.HOST_FEATURES))
@@ -145,31 +145,31 @@ def _fused_vs_reference(exact_shapes: bool):
     return sweep_drift(pairs)
 
 
-def test_shape_sweep_fused_step_within_bound():
+def test_shape_sweep_fused_step_within_bound(monkeypatch):
     """The full fused program (split-encoder hoisting + unroll + fused
     Pareto tail, padding disabled from the exact-shape policy) vs the
     Tier-0 reference at every swept shape — the acceptance criterion's
     fused == unfused proof."""
-    worst = _fused_vs_reference(exact_shapes=False)
+    worst = _fused_vs_reference(monkeypatch, exact_shapes=False)
     assert worst["max_ulp"] <= TIER1_MAX_ULP
 
 
-def test_shape_sweep_exact_shapes_within_bound():
+def test_shape_sweep_exact_shapes_within_bound(monkeypatch):
     """Same sweep with exact-shape batches enabled: counts 5 and 9 run at
     their exact widths instead of buckets 8/16, exercising the
     batch-width drift source on top of the fused rewrite."""
-    worst = _fused_vs_reference(exact_shapes=True)
+    worst = _fused_vs_reference(monkeypatch, exact_shapes=True)
     assert worst["max_ulp"] <= TIER1_MAX_ULP
 
 
-def _tenant_step_vs_reference(unroll: int):
+def _tenant_step_vs_reference(monkeypatch, unroll: int):
     """Ticks of the tenant step (three tenants in slots 0, 2, 3 of four,
     one new host row each per tick, their rows summing to each swept
     count) vs each tenant's Tier-0 prediction from its own window, left
     padded while it holds fewer than ``horizon`` rows."""
     n_hosts, max_tasks = 6, 5
-    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks,
-                              unroll=unroll)
+    pred = StragglerPredictor(n_hosts=n_hosts, max_tasks=max_tasks)
+    monkeypatch.setattr(pred, "_unroll", lambda nb: unroll)
     pred.open_slab(4)
     rng = np.random.default_rng(11)
     t = pred.horizon
@@ -198,11 +198,11 @@ def _tenant_step_vs_reference(unroll: int):
 
 
 @pytest.mark.parametrize("unroll", [1, 2, 5])
-def test_shape_sweep_tenant_step_within_bound(unroll):
+def test_shape_sweep_tenant_step_within_bound(monkeypatch, unroll):
     """The service's tenant step (segmented encoder over the slab's
     windows, unrolled scan, fused tail) vs per-tenant reference
     predictions, at every swept row count and each unroll factor."""
-    worst = _tenant_step_vs_reference(unroll)
+    worst = _tenant_step_vs_reference(monkeypatch, unroll)
     assert worst["max_ulp"] <= TIER1_MAX_ULP
 
 

@@ -38,9 +38,9 @@ TIER1_REL = 1e-5
 TIER1_ABS_FLOOR = 1e-6
 
 #: Maximum float32 ulp distance observed across the committed shape
-#: sweeps, re-pinned whenever a new Tier-1 optimization lands.  This is a
-#: *trajectory* number (benchmarks/check_perf.py warns when it grows),
-#: not a gate — the gate is TIER1_REL.
+#: sweeps, re-pinned whenever a new Tier-1 optimization lands.  The shape
+#: sweeps in test_tolerance.py assert it, to keep the drift trajectory
+#: visible; the numeric gate is TIER1_REL.
 TIER1_MAX_ULP = 64
 
 
@@ -65,8 +65,8 @@ def ulp_diff(a, b) -> np.ndarray:
 def drift(actual, desired) -> dict:
     """Max drift of ``actual`` vs ``desired`` as a dict of scalars:
     ``{"max_rel", "max_abs", "max_ulp"}``.  Used by the shape-sweep
-    tests and by ``benchmarks/engine_bench.py`` to record the Tier-1
-    drift trajectory into ``BENCH_engine.json``."""
+    tests and, through :func:`assert_tier1`, by ``chip_smoke.py``'s
+    Tier-1 check on the chip."""
     actual = np.asarray(actual, np.float32)
     desired = np.asarray(desired, np.float32)
     if actual.shape != desired.shape:
